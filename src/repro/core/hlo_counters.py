@@ -562,13 +562,15 @@ def _fusion_write_bytes(fcomp: Computation) -> float:
 
 
 def _is_convert_like(inst: Instruction, comps: Dict[str, Computation]) -> bool:
-    """A ``convert``, or a ``call`` whose callee is nothing but one root
-    convert of its parameter (the CPU backend's sharded "parallel_convert"
-    wrapper around large buffers)."""
+    """A ``convert``, or a ``call``/``fusion`` whose callee is nothing but
+    one root convert of its parameter (the CPU backend's sharded
+    "parallel_convert" call and its "wrapped_convert" fusion around large
+    buffers)."""
     if inst.opcode == "convert":
         return True
-    if inst.opcode == "call":
-        m = _TO_APPLY_RE.search(inst.attrs)
+    if inst.opcode in ("call", "fusion"):
+        m = (_TO_APPLY_RE if inst.opcode == "call" else _CALLS_RE).search(
+            inst.attrs)
         callee = comps.get(m.group(1)) if m else None
         if callee is not None:
             body = [i for i in callee.order if i.opcode != "parameter"]

@@ -1,6 +1,6 @@
 """Dispatch wrapper for the Pallas decode-attention kernel.
 
-Runs the real kernel on TPU and interpret mode elsewhere (CPU smoke/tests).
+Runs the real kernel on TPU and interpret mode on the CPU backend (tests).
 Called from inside the jitted decode step (transformer.attn_decode when
 ``cfg.attention_impl == "pallas"``), so no jit wrapper here.
 """
@@ -10,15 +10,10 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import auto_interpret
 from repro.kernels.decode_attention import decode as _decode
 from repro.kernels.decode_attention import paged as _paged
 from repro.kernels.decode_attention import prefill_paged as _prefill
-
-
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -29,7 +24,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     None.  Returns (B, 1, H, D)."""
     return _decode.decode_attention_fwd(
         q, k, v, kv_len, kv_start, block_kv=block_kv,
-        interpret=_auto_interpret(interpret))
+        interpret=auto_interpret(interpret))
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -39,18 +34,19 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
-    """q (B, 1, H, D); k_pool, v_pool (L, num_pages, page, KV, D) stacked
-    pools (4D single-layer accepted); block_table (B, max_blocks) int32
-    (page 0 = reserved null page); kv_len (B,) int32 per-slot token counts;
+    """q (B, 1, H, D); k_pool, v_pool (L, num_pages, KV, page, D) stacked
+    head-major pools (4D single-layer accepted); block_table
+    (B, max_blocks) int32 (page 0 = reserved null page); kv_len (B,) int32
+    per-slot token counts;
     layer — pool layer to address; pages_per_step — page-list blocking
     factor (P pages swept per grid step); k_scale, v_scale — optional
-    (L, num_pages, page, KV) f32 per-row scales for int8 pools.
+    (L, num_pages, KV, page, 1) f32 per-row scales for int8 pools.
     Returns (B, 1, H, D)."""
     return _paged.paged_decode_attention_fwd(
         q, k_pool, v_pool, block_table, kv_len, layer,
         k_scale=k_scale, v_scale=v_scale,
         pages_per_step=pages_per_step,
-        interpret=_auto_interpret(interpret))
+        interpret=auto_interpret(interpret))
 
 
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
@@ -63,15 +59,16 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             interpret: Optional[bool] = None) -> jax.Array:
     """Ragged multi-token paged prefill: q (B, T, H, D) chunk (its K/V
     rows already scattered into the pool); k_pool, v_pool
-    (L, num_pages, page, KV, D) stacked pools (4D single-layer accepted);
+    (L, num_pages, KV, page, D) stacked head-major pools (4D single-layer
+    accepted);
     block_table (B, max_blocks) int32 (page 0 = reserved null page);
     base_len (B,) int32 tokens resident before the chunk; new_len (B,)
     int32 = base_len + granted chunk tokens; layer — pool layer to
     address; pages_per_step — page-list blocking factor (P pages swept
-    per grid step); k_scale, v_scale — optional (L, num_pages, page, KV)
-    f32 per-row scales for int8 pools.  Returns (B, T, H, D)."""
+    per grid step); k_scale, v_scale — optional (L, num_pages, KV, page,
+    1) f32 per-row scales for int8 pools.  Returns (B, T, H, D)."""
     return _prefill.paged_prefill_attention_fwd(
         q, k_pool, v_pool, block_table, base_len, new_len, layer,
         k_scale=k_scale, v_scale=v_scale,
         pages_per_step=pages_per_step,
-        interpret=_auto_interpret(interpret))
+        interpret=auto_interpret(interpret))

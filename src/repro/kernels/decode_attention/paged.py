@@ -1,7 +1,7 @@
 """Paged single-token decode attention as a Pallas TPU kernel.
 
 The paged serving hot path: one new query per slot attends over that slot's
-PAGES of a shared (num_pages, page, KV, D) pool.  The physical page holding
+PAGES of a shared (num_pages, KV, page, D) pool.  The physical page holding
 each logical block comes from a scalar-prefetched block table — the DMA
 address is computed from SMEM before the tile is fetched, so the kernel
 streams ONLY the pages a slot actually owns.  That is the point for the
@@ -32,11 +32,18 @@ Shape strategy (mirrors the dense decode kernel in ``decode.py``):
     of grid step j, and pages at or beyond the slot's length are skipped
     with ``pl.when`` (their table entries point at the reserved null page
     0, so the prefetch address is always valid).
-  * the pool stays STACKED (L, num_pages, page, KV, D): the layer-scan
-    caller passes its trip counter as the ``layer`` scalar and the
-    index_map addresses (layer, page) directly — no per-layer pool slice
+  * the pool stays STACKED across layers: the layer-scan caller passes
+    its trip counter as the ``layer`` scalar and the index_map
+    addresses (layer, page, head) directly — no per-layer pool slice
     is ever materialized (a dynamic-slice of the full pool per layer is
     exactly the max_seq-proportional traffic the paged design removes).
+  * HEAD-MAJOR pages (L, num_pages, KV, page, D): one (slot, kv-head)
+    tile is the block (1, 1, 1, page, D), whose last two dims are the
+    whole (page, D) plane of the array — the Mosaic TPU compiler accepts
+    such a block at any page size, where a (page, 1, D) slice of a
+    token-major (page, KV, D) page is refused (a block's last two dims
+    must be (8, 128)-divisible or whole).  int8 scales ride the same
+    way as (L, num_pages, KV, page, 1).
 
 Inference-only: no VJP (the jnp gather oracle in ``ref.py`` carries
 gradients where needed).
@@ -99,11 +106,11 @@ def _kernel(kvlen_ref, tbl_ref, layer_ref, q_ref, *refs, scale: float,
 
         @pl.when(bj * page < kv_len)
         def _body():
-            k = k_ref[0, 0, :, 0].astype(jnp.float32)    # (page, D)
-            v = v_ref[0, 0, :, 0].astype(jnp.float32)
+            k = k_ref[0, 0, 0].astype(jnp.float32)       # (page, D)
+            v = v_ref[0, 0, 0].astype(jnp.float32)
             if quantized:                # dequantize in the f32 accumulator
-                k = k * ks_ref[0, 0, :, 0][:, None]
-                v = v * vs_ref[0, 0, :, 0][:, None]
+                k = k * ks_ref[0, 0, 0]                  # (page, 1) scales
+                v = v * vs_ref[0, 0, 0]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale   # (G, page)
@@ -137,13 +144,14 @@ def paged_decode_attention_fwd(q: jax.Array, k_pool: jax.Array,
                                v_scale: jax.Array | None = None,
                                pages_per_step: int = 1,
                                interpret: bool = False) -> jax.Array:
-    """q (B, 1, H, D); k_pool, v_pool (L, num_pages, page, KV, D) stacked
-    pools (a 4D (num_pages, page, KV, D) single-layer pool is promoted);
+    """q (B, 1, H, D); k_pool, v_pool (L, num_pages, KV, page, D) stacked
+    head-major pools (a 4D (num_pages, KV, page, D) single-layer pool is
+    promoted);
     block_table (B, max_blocks) int32 physical page ids (0 = reserved null
     page for unallocated blocks); kv_len (B,) int32 per-slot token counts
     (positions >= kv_len[b] are masked); layer — which pool layer to
     address (the layer-scan trip counter); k_scale, v_scale — optional
-    (L, num_pages, page, KV) f32 per-row-per-head scales for int8 pools
+    (L, num_pages, KV, page, 1) f32 per-row-per-head scales for int8 pools
     (each page's scale rows ride the same scalar-prefetched address as the
     page itself; int8 tiles are upcast and scaled inside the f32
     online-softmax accumulator); pages_per_step — pages swept per grid
@@ -156,7 +164,7 @@ def paged_decode_attention_fwd(q: jax.Array, k_pool: jax.Array,
         k_pool, v_pool = k_pool[None], v_pool[None]
         if quantized:
             k_scale, v_scale = k_scale[None], v_scale[None]
-    _, num_pages, page, KV, _ = k_pool.shape
+    _, num_pages, KV, page, _ = k_pool.shape
     NB = block_table.shape[1]
     P = max(1, pages_per_step)
     steps = grid_steps(NB, P)
@@ -175,18 +183,13 @@ def paged_decode_attention_fwd(q: jax.Array, k_pool: jax.Array,
     def _page_map(p):
         # the p-th page of grid step j: physical id tbl[b*NBp + j*P + p]
         def index_map(b, h, j, kvl_ref, tbl_ref, lay_ref):
-            return (lay_ref[0], tbl_ref[b * NBp + j * P + p], 0, h, 0)
+            return (lay_ref[0], tbl_ref[b * NBp + j * P + p], h, 0, 0)
         return index_map
 
-    def _scale_map(p):
-        # scale rows of the same physical page (no head-dim axis)
-        def index_map(b, h, j, kvl_ref, tbl_ref, lay_ref):
-            return (lay_ref[0], tbl_ref[b * NBp + j * P + p], 0, h)
-        return index_map
-
-    page_spec = [pl.BlockSpec((1, 1, page, 1, D), _page_map(p))
+    page_spec = [pl.BlockSpec((1, 1, 1, page, D), _page_map(p))
                  for p in range(P)]
-    scale_spec = [pl.BlockSpec((1, 1, page, 1), _scale_map(p))
+    # scale rows of the same physical page: (page, 1) per kv head
+    scale_spec = [pl.BlockSpec((1, 1, 1, page, 1), _page_map(p))
                   for p in range(P)]
     scale_ins = ([*scale_spec, *scale_spec] if quantized else [])
     scale_args = (([k_scale] * P + [v_scale] * P) if quantized else [])

@@ -51,8 +51,11 @@ Shape strategy (mirrors the single-token paged decode kernel in
     blocking arithmetic).
   * GQA without materializing repeated kv heads: each page runs
     [T*G, D] x [D, page] on the MXU.
-  * the pool stays STACKED (L, num_pages, page, KV, D); the layer-scan
-    caller passes its trip counter as the ``layer`` scalar.
+  * the pool stays STACKED and head-major, (L, num_pages, KV, page, D);
+    the layer-scan caller passes its trip counter as the ``layer``
+    scalar.  One (slot, kv-head) page tile is the whole (page, D) plane
+    of the array, the block shape the TPU compiler accepts (see
+    ``paged.py``).
 
 Inference-only: no VJP (the jnp gather oracle in ``ref.py`` carries
 gradients where needed).
@@ -108,11 +111,11 @@ def _kernel(base_ref, len_ref, tbl_ref, layer_ref, q_ref, *refs,
 
         @pl.when(bj * page < kv_len)
         def _body():
-            k = k_ref[0, 0, :, 0].astype(jnp.float32)    # (page, D)
-            v = v_ref[0, 0, :, 0].astype(jnp.float32)
+            k = k_ref[0, 0, 0].astype(jnp.float32)       # (page, D)
+            v = v_ref[0, 0, 0].astype(jnp.float32)
             if quantized:                # dequantize in the f32 accumulator
-                k = k * ks_ref[0, 0, :, 0][:, None]
-                v = v * vs_ref[0, 0, :, 0][:, None]
+                k = k * ks_ref[0, 0, 0]                  # (page, 1) scales
+                v = v * vs_ref[0, 0, 0]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale   # (T*G, page)
@@ -151,14 +154,14 @@ def paged_prefill_attention_fwd(q: jax.Array, k_pool: jax.Array,
                                 pages_per_step: int = 1,
                                 interpret: bool = False) -> jax.Array:
     """q (B, T, H, D) — the chunk's query block (its K/V rows must already
-    be scattered into the pool); k_pool, v_pool (L, num_pages, page, KV, D)
-    stacked pools (a 4D single-layer pool is promoted); block_table
+    be scattered into the pool); k_pool, v_pool (L, num_pages, KV, page, D)
+    stacked head-major pools (a 4D single-layer pool is promoted); block_table
     (B, max_blocks) int32 physical page ids (0 = reserved null page);
     base_len (B,) int32 tokens resident before the chunk; new_len (B,)
     int32 = base_len + granted chunk tokens (rows past a slot's grant are
     masked like the oracle and ignored by the caller); layer — which pool
-    layer to address; k_scale, v_scale — optional (L, num_pages, page, KV)
-    f32 per-row-per-head scales for int8 pools, dequantized inside the
+    layer to address; k_scale, v_scale — optional (L, num_pages, KV, page,
+    1) f32 per-row-per-head scales for int8 pools, dequantized inside the
     page sweep; pages_per_step — pages swept per grid step (1 = the
     original one-page grid).  Returns (B, T, H, D).
     """
@@ -168,7 +171,7 @@ def paged_prefill_attention_fwd(q: jax.Array, k_pool: jax.Array,
         k_pool, v_pool = k_pool[None], v_pool[None]
         if quantized:
             k_scale, v_scale = k_scale[None], v_scale[None]
-    _, num_pages, page, KV, _ = k_pool.shape
+    _, num_pages, KV, page, _ = k_pool.shape
     NB = block_table.shape[1]
     P = max(1, pages_per_step)
     steps = grid_steps(NB, P)
@@ -191,18 +194,13 @@ def paged_prefill_attention_fwd(q: jax.Array, k_pool: jax.Array,
     def _page_map(p):
         # the p-th page of grid step j: physical id tbl[b*NBp + j*P + p]
         def index_map(b, h, j, base_ref, len_ref, tbl_ref, lay_ref):
-            return (lay_ref[0], tbl_ref[b * NBp + j * P + p], 0, h, 0)
+            return (lay_ref[0], tbl_ref[b * NBp + j * P + p], h, 0, 0)
         return index_map
 
-    def _scale_map(p):
-        # scale rows of the same physical page (no head-dim axis)
-        def index_map(b, h, j, base_ref, len_ref, tbl_ref, lay_ref):
-            return (lay_ref[0], tbl_ref[b * NBp + j * P + p], 0, h)
-        return index_map
-
-    page_spec = [pl.BlockSpec((1, 1, page, 1, D), _page_map(p))
+    page_spec = [pl.BlockSpec((1, 1, 1, page, D), _page_map(p))
                  for p in range(P)]
-    scale_spec = [pl.BlockSpec((1, 1, page, 1), _scale_map(p))
+    # scale rows of the same physical page: (page, 1) per kv head
+    scale_spec = [pl.BlockSpec((1, 1, 1, page, 1), _page_map(p))
                   for p in range(P)]
     scale_ins = ([*scale_spec, *scale_spec] if quantized else [])
     scale_args = (([k_scale] * P + [v_scale] * P) if quantized else [])

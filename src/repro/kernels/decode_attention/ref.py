@@ -37,17 +37,18 @@ def _gather_logical(q, k_pool, v_pool, block_table, layer,
         if k_scale is not None:
             k_scale, v_scale = k_scale[None], v_scale[None]
     B = block_table.shape[0]
-    _, _, page, KV, D = k_pool.shape
+    _, _, KV, page, D = k_pool.shape
     NB = block_table.shape[1]
-    kg = k_pool[layer, block_table]          # (B, NB, page, KV, D)
+    kg = k_pool[layer, block_table]          # (B, NB, KV, page, D)
     vg = v_pool[layer, block_table]
     if k_scale is not None:                  # int8 pages + per-row scales
-        kg = kg.astype(jnp.float32) * k_scale[layer, block_table][..., None]
-        vg = vg.astype(jnp.float32) * v_scale[layer, block_table][..., None]
+        kg = kg.astype(jnp.float32) * k_scale[layer, block_table]
+        vg = vg.astype(jnp.float32) * v_scale[layer, block_table]
         kg = kg.astype(q.dtype)
         vg = vg.astype(q.dtype)
-    return (kg.reshape(B, NB * page, KV, D),
-            vg.reshape(B, NB * page, KV, D))
+    # head-major pages -> the token-major logical view
+    return (kg.transpose(0, 1, 3, 2, 4).reshape(B, NB * page, KV, D),
+            vg.transpose(0, 1, 3, 2, 4).reshape(B, NB * page, KV, D))
 
 
 def paged_decode_attention_ref(q: jax.Array, k_pool: jax.Array,
@@ -56,11 +57,12 @@ def paged_decode_attention_ref(q: jax.Array, k_pool: jax.Array,
                                k_scale: Optional[jax.Array] = None,
                                v_scale: Optional[jax.Array] = None
                                ) -> jax.Array:
-    """q (B, 1, H, D); k_pool, v_pool (L, num_pages, page, KV, D) stacked
-    pools (4D single-layer pools are promoted); block_table (B, max_blocks)
-    int32; kv_len (B,) int32 per-slot token counts; layer — the pool layer
-    to address; k_scale, v_scale — optional (L, num_pages, page, KV) f32
-    per-row scales for int8 pools (dequantized after the gather).  Gathers
+    """q (B, 1, H, D); k_pool, v_pool (L, num_pages, KV, page, D) stacked
+    head-major pools (4D single-layer pools are promoted); block_table
+    (B, max_blocks) int32; kv_len (B,) int32 per-slot token counts; layer —
+    the pool layer to address; k_scale, v_scale — optional
+    (L, num_pages, KV, page, 1) f32 per-row scales for int8 pools
+    (dequantized after the gather).  Gathers
     each slot's pages into its logical (max_blocks*page, KV, D) view in ONE
     (layer, page) gather — live pages only, never the whole pool — then
     masks positions >= kv_len[b].  Returns (B, 1, H, D)."""

@@ -9,21 +9,16 @@ from functools import partial
 
 import jax
 
+from repro.kernels import auto_interpret
 from repro.kernels.flash_attention import flash as _flash
 from repro.models.flash import flash_attention_ref
-
-
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_vjp(q, k, v, causal, block_q, block_kv):
     return _flash.flash_attention_fwd(
         q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
-        interpret=_auto_interpret(None))
+        interpret=auto_interpret())
 
 
 def _fwd(q, k, v, causal, block_q, block_kv):

@@ -6,14 +6,9 @@ from functools import partial
 
 import jax
 
+from repro.kernels import auto_interpret
 from repro.kernels.ssm_scan import scan as _scan
 from repro.models.ssm import mamba1_scan
-
-
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 @partial(jax.jit, static_argnames=("block_d", "chunk", "interpret"))
@@ -21,4 +16,4 @@ def selective_scan(x, dt, A, Bc, Cc, block_d: int = 256, chunk: int = 256,
                    interpret=None):
     return _scan.selective_scan_fwd(
         x, dt, A, Bc, Cc, block_d=block_d, chunk=chunk,
-        interpret=_auto_interpret(interpret))
+        interpret=auto_interpret(interpret))

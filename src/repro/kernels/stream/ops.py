@@ -1,8 +1,8 @@
 """Jitted public wrappers for the BabelStream-TPU suite.
 
-``interpret=None`` auto-selects: real Pallas lowering on TPU backends,
-interpret mode (Python execution of the kernel body) on CPU — which is how
-this container validates the kernels.
+``interpret=None`` auto-selects (``repro.kernels.auto_interpret``): real
+Pallas lowering on TPU, interpret mode (Python execution of the kernel
+body) on the CPU backend, an error on any other backend.
 """
 from __future__ import annotations
 
@@ -10,35 +10,30 @@ from functools import partial
 
 import jax
 
+from repro.kernels import auto_interpret
 from repro.kernels.stream import stream
-
-
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def stream_copy(a, interpret=None):
-    return stream.copy(a, interpret=_auto_interpret(interpret))
+    return stream.copy(a, interpret=auto_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def stream_mul(c, interpret=None):
-    return stream.mul(c, interpret=_auto_interpret(interpret))
+    return stream.mul(c, interpret=auto_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def stream_add(a, b, interpret=None):
-    return stream.add(a, b, interpret=_auto_interpret(interpret))
+    return stream.add(a, b, interpret=auto_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def stream_triad(b, c, interpret=None):
-    return stream.triad(b, c, interpret=_auto_interpret(interpret))
+    return stream.triad(b, c, interpret=auto_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def stream_dot(a, b, interpret=None):
-    return stream.dot(a, b, interpret=_auto_interpret(interpret))
+    return stream.dot(a, b, interpret=auto_interpret(interpret))

@@ -10,19 +10,11 @@ from __future__ import annotations
 import jax
 
 
-def _auto_axis_types(n: int):
-    """jax >= 0.5 wants explicit AxisType.Auto; older jax has no AxisType
-    (every axis is implicitly auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_devices: int = 1):
@@ -31,4 +23,4 @@ def make_host_mesh(n_devices: int = 1):
     devs = jax.devices()[:n_devices]
     return jax.sharding.Mesh(
         np.asarray(devs).reshape(len(devs), 1), ("data", "model"),
-        **_auto_axis_types(2))
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -1,6 +1,9 @@
 """Serving launcher: production mesh + the paged continuous-batching engine.
 
-On this container run --local-smoke (reduced config, real engine).  The
+On a CPU host run --local-smoke (reduced config, real engine).  At full
+width the default granite-8b (~16.5 GB of bf16 weights) does not fit one
+16 GB TPU v5e; phi4-mini-3.8b (~7.7 GB) does, and ``chip_smoke.py`` at the
+checkout root serves it through ``build_engine``.  The
 production path is the ``PagedEngine`` (refcounted page pool with prefix
 sharing + copy-on-write, tick scheduler with partial grants, chunked
 prefill through the one fused decode cell); --whole-batch falls back to
@@ -8,9 +11,14 @@ lockstep whole-batch generation (``ServingEngine``), --legacy-loop to the
 per-token host loop, both kept for measured comparison.
 """
 import argparse
+import os
 import sys
+from pathlib import Path
 
 import numpy as np
+
+# the checkout root (src/repro/launch/serve.py -> three levels up)
+REPO_ROOT = Path(__file__).resolve().parents[3]
 
 
 class SpecCapacityError(ValueError):
@@ -19,6 +27,42 @@ class SpecCapacityError(ValueError):
     PLUS k in-flight proposals, and ``PagedKVCache.ensure`` raising
     mid-run (after minutes of serving) is the failure mode this
     startup check replaces."""
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache before the first
+    compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and nothing else is set here; otherwise the cache lives at the
+    fixed path ``<checkout>/.jax_cache`` (the path is part of the cache
+    key, so it must not move between runs)."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+
+
+def build_engine(cfg, scfg, *, draft_cfg=None, whole_batch: bool = False):
+    """The model for ``cfg`` with seeded random weights, and the serving
+    engine over it: the ``PagedEngine`` (with a draft model when
+    ``draft_cfg`` is given), or the lockstep ``ServingEngine`` when
+    ``whole_batch``.  Weights are initialised in one jitted program — an
+    eager init materializes each stacked leaf in f32 before the cast, a
+    transient that a 3.8B model cannot afford next to its weights on one
+    16 GB chip."""
+    import jax
+    from repro.models import get_model
+    from repro.serve.engine import PagedEngine, ServingEngine
+
+    model = get_model(cfg)
+    params = jax.jit(model.init)(jax.random.key(0))
+    if whole_batch:
+        return ServingEngine(model, params, scfg)
+    draft_model = draft_params = None
+    if draft_cfg is not None:
+        draft_model = get_model(draft_cfg)
+        draft_params = jax.jit(draft_model.init)(jax.random.key(1))
+    return PagedEngine(model, params, scfg, draft_model=draft_model,
+                       draft_params=draft_params)
 
 
 def main() -> int:
@@ -212,10 +256,8 @@ def main() -> int:
               f"of the page size (the same alignment guidance as "
               f"--sys-prompt-tokens below)")
 
-    import jax
     from repro import configs
-    from repro.models import get_model
-    from repro.serve.engine import PagedEngine, ServeConfig, ServingEngine
+    from repro.serve.engine import ServeConfig
 
     cfg = configs.get(args.arch)
     if args.local_smoke:
@@ -232,9 +274,7 @@ def main() -> int:
               f"sharer will copy-on-write the partial trailing page; align "
               f"shared system prompts to the page size for zero-copy "
               f"sharing")
-    model = get_model(cfg)
-    params = model.init(jax.random.key(0))
-    draft_model = draft_params = None
+    dcfg = None
     if args.spec_k:
         dcfg = configs.get(args.draft_arch)
         if args.local_smoke:
@@ -243,8 +283,6 @@ def main() -> int:
             ap.error(f"--draft-arch {args.draft_arch!r} has vocab "
                      f"{dcfg.vocab_size}, target has {cfg.vocab_size} — "
                      f"speculation needs a shared tokenizer")
-        draft_model = get_model(dcfg)
-        draft_params = draft_model.init(jax.random.key(1))
     # 2x batch requests of (prompt<=16 + new_tokens) tokens each; the paged
     # engine recycles pages across requests so max_seq only bounds ONE
     # request's span, not the engine's lifetime
@@ -307,9 +345,11 @@ def main() -> int:
                        snapshot_dir=args.snapshot_dir,
                        spec_k=args.spec_k)
     rng = np.random.RandomState(0)
+    enable_compile_cache()
+    engine = build_engine(cfg, scfg, draft_cfg=dcfg,
+                          whole_batch=args.whole_batch)
 
     if args.whole_batch:
-        engine = ServingEngine(model, params, scfg)
         prompts = [rng.randint(0, cfg.vocab_size, size=rng.randint(4, 16)
                                ).astype(np.int32) for _ in range(args.batch)]
         outs = engine.generate_batch(prompts)
@@ -319,8 +359,6 @@ def main() -> int:
               f"across {len(outs)} requests ({mode})")
         return 0
 
-    engine = PagedEngine(model, params, scfg,
-                         draft_model=draft_model, draft_params=draft_params)
     # pool capacity banner: resident tokens per HBM byte is the quantized-
     # pool payoff (int8 + per-row f32 scales vs 2-byte bf16 rows); a draft
     # pool, when speculating, is extra HBM the speedup has to pay for

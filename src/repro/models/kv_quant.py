@@ -1,8 +1,8 @@
 """Symmetric int8 row quantization for the paged KV page pools.
 
 The page pools store K/V rows as int8 with one f32 scale PER ROW PER KV
-HEAD (pool shape (L, num_pages, page, KV, hd) -> scale shape
-(L, num_pages, page, KV)).  Per-row scales are the smallest granularity
+HEAD (pool shape (L, num_pages, KV, page, hd) -> scale shape
+(L, num_pages, KV, page, 1)).  Per-row scales are the smallest granularity
 that keeps the serving invariants intact:
 
   * incremental append writes exactly its own row's scale — a page-level
@@ -28,20 +28,23 @@ QMAX = 127.0
 
 
 def quantize_rows(x):
-    """Quantize ``x`` (..., hd) -> (int8 rows (..., hd), f32 scales (...)).
+    """Quantize ``x`` (..., hd) -> (int8 rows (..., hd), f32 scales
+    (..., 1)) — the scale keeps a unit trailing axis, the layout of the
+    scale pools.
 
     All-zero rows get scale 1.0 so dequantization is exact (zeros) and the
     null page stays all-zero in both pools.
     """
     xf = x.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(xf), axis=-1)
+    absmax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / QMAX, 1.0).astype(jnp.float32)
-    q = jnp.clip(jnp.round(xf / scale[..., None]), -QMAX, QMAX)
+    q = jnp.clip(jnp.round(xf / scale), -QMAX, QMAX)
     return q.astype(jnp.int8), scale
 
 
 def dequantize_rows(q, scale):
-    """Inverse of ``quantize_rows``: int8 rows (..., hd) + f32 scales (...)
-    -> f32 rows.  Exact for the rows ``quantize_rows`` produced (round-trip
-    error is bounded by scale/2 per element, zero for all-zero rows)."""
-    return q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)
+    """Inverse of ``quantize_rows``: int8 rows (..., hd) + f32 scales
+    (..., 1) -> f32 rows.  Exact for the rows ``quantize_rows`` produced
+    (round-trip error is bounded by scale/2 per element, zero for all-zero
+    rows)."""
+    return q.astype(jnp.float32) * scale.astype(jnp.float32)

@@ -36,16 +36,6 @@ from repro.configs.base import ArchConfig
 from repro.dist.sharding import active_rules, current_mesh
 from repro.models.layers import dense
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                          # jax < 0.6 compat
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-
 def route(xf: jax.Array, router_w: jax.Array, cfg: ArchConfig
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """xf (n, d) -> (gates (n,k), experts (n,k), probs (n,E)).
@@ -188,12 +178,12 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w1: jax.Array, w2: jax.Array,
     if w3 is None:
         island_fn = lambda xf, g8, e8, w1l, w2l: island(xf, g8, e8, w1l,
                                                         w2l, None)
-        sm = _shard_map(island_fn, mesh=mesh,
-                        in_specs=in_specs[:5], out_specs=tok_spec,
-                        check_vma=False)
+        sm = jax.shard_map(island_fn, mesh=mesh,
+                           in_specs=in_specs[:5], out_specs=tok_spec,
+                           check_vma=False)
         out = sm(xf_full, gates, experts, w1, w2)
     else:
-        sm = _shard_map(island, mesh=mesh, in_specs=in_specs,
-                        out_specs=tok_spec, check_vma=False)
+        sm = jax.shard_map(island, mesh=mesh, in_specs=in_specs,
+                           out_specs=tok_spec, check_vma=False)
         out = sm(xf_full, gates, experts, w1, w2, w3)
     return out.reshape(B, S, d).astype(x.dtype), aux

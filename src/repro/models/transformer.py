@@ -251,11 +251,22 @@ def attn_decode(h, p, cfg: ArchConfig, rope, k_cache, v_cache, pos,
     return out, k_cache, v_cache
 
 
+def _row_index(layer, phys, off, n_kv):
+    """Index of N appended K/V rows in a head-major (L, pages, KV, page, ...)
+    pool: row n, head h lands at (layer, phys[n], h, off[n]), so ``.at[]``
+    takes (N, KV, ...) updates.  Naming every head explicitly keeps all
+    four indexed dims major, so the row scatter runs in the pool's own
+    layout; slicing the head axis with ``:`` instead makes XLA relayout
+    the whole pool around every append."""
+    heads = jnp.arange(n_kv, dtype=jnp.int32)[None, :]
+    return layer, phys[:, None], heads, off[:, None]
+
+
 def attn_decode_paged(h, p, cfg: ArchConfig, rope, k_pool, v_pool, layer,
                       table, lengths, active, k_scale=None, v_scale=None):
     """decode path over the paged KV pool: h (B, 1, d); k_pool/v_pool are
-    the STACKED (L, num_pages, page, KV, hd) pools — appended to and
-    gathered from with an explicit (layer, page) scatter/gather so no
+    the STACKED head-major (L, num_pages, KV, page, hd) pools — appended
+    to and gathered from with an explicit (layer, page) scatter/gather so no
     pool-sized per-layer slice is ever materialized (that slice is exactly
     the max_seq-proportional traffic the paged cache removes; the HLO
     census asserts the step's bytes scale with live pages).  table
@@ -280,28 +291,29 @@ def attn_decode_paged(h, p, cfg: ArchConfig, rope, k_pool, v_pool, layer,
 
     With quantized pools (k_scale/v_scale not None) the appended row is
     int8-quantized per KV head and the row's f32 scale lands at the same
-    (layer, page, row) address — the scale travels with its page."""
+    (layer, page, head, row) address — the scale travels with its page."""
     hn = apply_norm(h, p["ln1"], cfg)
     a = p["attn"]
     q, k, v = _qkv(hn, a, cfg, rope, decode=True)
     B = h.shape[0]
-    page = k_pool.shape[2]
+    page = k_pool.shape[3]
     nb = table.shape[1]
     blk = jnp.minimum(lengths // page, nb - 1)
     phys = jnp.where(active, table[jnp.arange(B), blk], 0)
     off = lengths % page
+    at = _row_index(layer, phys, off, k.shape[2])
     if k_scale is not None:                # quantize the appended row
-        kq, ks = quantize_rows(k[:, 0])    # (B, KV, hd) int8, (B, KV) f32
+        kq, ks = quantize_rows(k[:, 0])    # (B, KV, hd) int8, (B, KV, 1) f32
         vq, vs = quantize_rows(v[:, 0])
-        k_pool = k_pool.at[layer, phys, off].set(kq)
-        v_pool = v_pool.at[layer, phys, off].set(vq)
-        k_scale = k_scale.at[layer, phys, off].set(ks)
-        v_scale = v_scale.at[layer, phys, off].set(vs)
-        k_scale = constrain(k_scale, None, "cache_seq", None, None)
-        v_scale = constrain(v_scale, None, "cache_seq", None, None)
+        k_pool = k_pool.at[at].set(kq)
+        v_pool = v_pool.at[at].set(vq)
+        k_scale = k_scale.at[at].set(ks)
+        v_scale = v_scale.at[at].set(vs)
+        k_scale = constrain(k_scale, None, "cache_seq", None, None, None)
+        v_scale = constrain(v_scale, None, "cache_seq", None, None, None)
     else:
-        k_pool = k_pool.at[layer, phys, off].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[layer, phys, off].set(v[:, 0].astype(v_pool.dtype))
+        k_pool = k_pool.at[at].set(k[:, 0].astype(k_pool.dtype))
+        v_pool = v_pool.at[at].set(v[:, 0].astype(v_pool.dtype))
     # keep the pool page-sharded through the in-place update
     k_pool = constrain(k_pool, None, "cache_seq", None, None, None)
     v_pool = constrain(v_pool, None, "cache_seq", None, None, None)
@@ -347,7 +359,7 @@ def attn_prefill_paged(h, p, cfg: ArchConfig, rope, k_pool, v_pool, layer,
     a = p["attn"]
     q, k, v = _qkv(hn, a, cfg, rope, decode=True)
     B, T, _ = h.shape
-    page = k_pool.shape[2]
+    page = k_pool.shape[3]
     nb = table.shape[1]
     tok_pos = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     blk = jnp.minimum(tok_pos // page, nb - 1)
@@ -356,19 +368,20 @@ def attn_prefill_paged(h, p, cfg: ArchConfig, rope, k_pool, v_pool, layer,
     off = (tok_pos % page).reshape(B * T)
     phys = phys.reshape(B * T)
     KV, hd = k.shape[2], k.shape[3]
+    at = _row_index(layer, phys, off, KV)
     if k_scale is not None:                # quantize all the chunk's rows
         kq, ks = quantize_rows(k.reshape(B * T, KV, hd))
         vq, vs = quantize_rows(v.reshape(B * T, KV, hd))
-        k_pool = k_pool.at[layer, phys, off].set(kq)
-        v_pool = v_pool.at[layer, phys, off].set(vq)
-        k_scale = k_scale.at[layer, phys, off].set(ks)
-        v_scale = v_scale.at[layer, phys, off].set(vs)
-        k_scale = constrain(k_scale, None, "cache_seq", None, None)
-        v_scale = constrain(v_scale, None, "cache_seq", None, None)
+        k_pool = k_pool.at[at].set(kq)
+        v_pool = v_pool.at[at].set(vq)
+        k_scale = k_scale.at[at].set(ks)
+        v_scale = v_scale.at[at].set(vs)
+        k_scale = constrain(k_scale, None, "cache_seq", None, None, None)
+        v_scale = constrain(v_scale, None, "cache_seq", None, None, None)
     else:
-        k_pool = k_pool.at[layer, phys, off].set(
+        k_pool = k_pool.at[at].set(
             k.reshape(B * T, KV, hd).astype(k_pool.dtype))
-        v_pool = v_pool.at[layer, phys, off].set(
+        v_pool = v_pool.at[at].set(
             v.reshape(B * T, KV, hd).astype(v_pool.dtype))
     # keep the pool page-sharded through the in-place update
     k_pool = constrain(k_pool, None, "cache_seq", None, None, None)
@@ -620,7 +633,7 @@ def lm_decode(params, cfg: ArchConfig, tokens, cache):
 
 
 def lm_decode_paged(params, cfg: ArchConfig, tokens, cache, active):
-    """tokens (B, 1); cache {"k"/"v" (L, num_pages, page, KV, hd) pools,
+    """tokens (B, 1); cache {"k"/"v" (L, num_pages, KV, page, hd) pools,
     "table" (B, max_blocks) int32, "length" (B,) int32}; active (B,) bool.
 
     The NON-LOCKSTEP decode step: every slot advances at its own
@@ -1008,9 +1021,11 @@ def cache_decls(cfg: ArchConfig, batch: int, max_seq: int) -> Dict[str, Any]:
 
 def paged_cache_decls(cfg: ArchConfig, batch: int, max_blocks: int,
                       page_size: int, num_pages: int) -> Dict[str, Any]:
-    """Paged decode cache: a shared page pool (num_pages, page, KV, hd) per
-    layer plus a per-slot block table and per-slot lengths — NO shared
-    position, NO start window.  Page 0 is the reserved null page (never
+    """Paged decode cache: a shared head-major page pool
+    (num_pages, KV, page, hd) per layer plus a per-slot block table and
+    per-slot lengths — NO shared position, NO start window.  Head-major
+    pages make one (page, kv-head) tile a whole (page, hd) plane, the
+    block the Pallas kernels fetch.  Page 0 is the reserved null page (never
     allocated; inactive slots' appends and unallocated table entries land
     there).  Several block tables may reference the SAME physical page
     (prefix sharing; see serve/cache.py for the refcount/COW discipline —
@@ -1019,28 +1034,28 @@ def paged_cache_decls(cfg: ArchConfig, batch: int, max_blocks: int,
     flash-decoding seq-sharding of the dense cache carried over page-wise.
 
     With ``cfg.kv_dtype == "int8"`` the pools are int8 and the cache grows
-    ``k_scale``/``v_scale`` — (L, num_pages, page, KV) f32 per-row-per-head
-    scales that travel WITH their pages through every copy path (COW,
-    defrag, retained-prefix adoption); see models/kv_quant.py."""
+    ``k_scale``/``v_scale`` — (L, num_pages, KV, page, 1) f32
+    per-row-per-head scales that travel WITH their pages through every
+    copy path (COW, defrag, retained-prefix adoption); see
+    models/kv_quant.py."""
     if cfg.mamba_version or cfg.is_encoder_decoder:
         raise ValueError("paged KV cache requires a decoder-only attention "
                          "LM (per-slot page tables)")
     hd, KV, L = cfg.head_dim, cfg.n_kv_heads, cfg.n_layers
     pool_axes = (None, "cache_seq", None, None, None)
-    scale_axes = (None, "cache_seq", None, None)
     bf = jnp.int8 if cfg.kv_quantized else cfg.param_dtype
     decls = {
-        "k": ParamDecl((L, num_pages, page_size, KV, hd), pool_axes,
+        "k": ParamDecl((L, num_pages, KV, page_size, hd), pool_axes,
                        "zeros", bf),
-        "v": ParamDecl((L, num_pages, page_size, KV, hd), pool_axes,
+        "v": ParamDecl((L, num_pages, KV, page_size, hd), pool_axes,
                        "zeros", bf),
         "table": ParamDecl((batch, max_blocks), ("batch", None), "zeros",
                            jnp.int32),
         "length": ParamDecl((batch,), ("batch",), "zeros", jnp.int32),
     }
     if cfg.kv_quantized:
-        decls["k_scale"] = ParamDecl((L, num_pages, page_size, KV),
-                                     scale_axes, "zeros", jnp.float32)
-        decls["v_scale"] = ParamDecl((L, num_pages, page_size, KV),
-                                     scale_axes, "zeros", jnp.float32)
+        decls["k_scale"] = ParamDecl((L, num_pages, KV, page_size, 1),
+                                     pool_axes, "zeros", jnp.float32)
+        decls["v_scale"] = ParamDecl((L, num_pages, KV, page_size, 1),
+                                     pool_axes, "zeros", jnp.float32)
     return decls

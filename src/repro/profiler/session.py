@@ -40,10 +40,7 @@ def profile_compiled(name: str, compiled, n_devices: int,
                                    runtime_s=max(terms.modeled_time_s, 1e-12),
                                    runtime_is_modeled=True)
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-            cost = cost[0] if cost else {}
-        cost = dict(cost)
+        cost = dict(compiled.cost_analysis())
     except Exception:                                 # pragma: no cover
         cost = {}
     try:

@@ -1,8 +1,8 @@
 """Paged KV cache manager: a refcounted page pool with prefix sharing and
 copy-on-write.
 
-Device state (``Model.init_paged_cache``): k/v page pools
-(L, num_pages, page, KV, hd), a block table (B, max_blocks) int32 and
+Device state (``Model.init_paged_cache``): head-major k/v page pools
+(L, num_pages, KV, page, hd), a block table (B, max_blocks) int32 and
 per-slot lengths (B,) int32.  The manager owns the host mirrors, the page
 FREE LIST and the per-page REFCOUNTS; page 0 is the reserved NULL page —
 never allocated, the landing zone for inactive slots' appends and
@@ -100,12 +100,16 @@ class RetainedPrefix:
 
 
 def _copy_pages(pool, dst, src):
-    """Device page copy on the stacked (L, num_pages, page, KV, hd) pool:
-    rows of pages ``src`` are written into pages ``dst`` (both (n,) int32).
-    Jitted with a donated pool so the copy is in place — the HLO is a
-    page-sized gather + scatter whose census bytes scale with the pages
-    copied, never with the pool."""
-    return pool.at[:, dst].set(pool[:, src])
+    """Device page copy on a stacked (L, num_pages, ...) pool: pages
+    ``src`` are written into pages ``dst`` (both (n,) int32).  Jitted with
+    a donated pool so the copy is in place — the HLO is a gather + scatter
+    of (L x n) page tiles whose census bytes scale with the pages copied,
+    never with the pool.  Every (layer, page) pair is indexed explicitly
+    so both indexed dims are the major ones: a plain ``pool[:, src]``
+    scatter on the page axis is lowered with whole-pool transposes
+    around it, which moves the whole pool per copy."""
+    layers = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
+    return pool.at[layers, dst[None, :]].set(pool[layers, src[None, :]])
 
 
 def _copy_pages_both(k, v, dst, src):
@@ -160,12 +164,9 @@ class PagedKVCache:
         # per-page bytes across BOTH pools (+ scale pools when quantized) —
         # derived from the ACTUAL pool itemsize so census byte gates stay
         # exact for any kv_dtype
-        L = self.k.shape[0]
-        self.page_bytes = 2 * L * self.page * self.k.shape[3] \
-            * self.k.shape[4] * self.k.dtype.itemsize
+        self.page_bytes = 2 * self.k.nbytes // self.num_pages
         if self.quantized:
-            self.page_bytes += 2 * L * self.page * self.k_scale.shape[3] \
-                * self.k_scale.dtype.itemsize
+            self.page_bytes += 2 * self.k_scale.nbytes // self.num_pages
         self.cow_copies = 0
         self.cow_bytes = 0
         self.cow_dispatches = 0          # device copy calls (1 per flush)

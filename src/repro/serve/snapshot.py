@@ -73,7 +73,10 @@ from repro.serve.cache import (PagedKVCache, RetainedPrefix,
                                prefix_digests)
 
 MAGIC = b"RPSNAP01"
-VERSION = 1
+# 2: head-major pools (L, num_pages, KV, page, hd) and scales
+# (L, num_pages, KV, page, 1); a version-1 file holds token-major pools
+# and is refused with SnapshotMismatchError, never misread
+VERSION = 2
 
 # PagedKVCache counters that ride along so a restored engine's stats and
 # bench sections stay continuous across the restart (none affect output)

@@ -142,8 +142,8 @@ def _paged_case(seed, B, H, KV, D, page, NB, L, extra_pages=3):
     P = B * NB + extra_pages
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, 1, H, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (L, P, page, KV, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (L, P, page, KV, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, P, KV, page, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, P, KV, page, D), jnp.float32)
     tbl = rng.permutation(np.arange(1, P))[:B * NB].reshape(B, NB)
     lens = rng.randint(1, NB * page + 1, size=B)
     layer = rng.randint(0, L)
@@ -246,8 +246,8 @@ def _prefill_case(seed, B, T, H, KV, D, page, NB, L, extra_pages=3,
     P = B * NB + extra_pages
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (L, P, page, KV, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (L, P, page, KV, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, P, KV, page, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, P, KV, page, D), jnp.float32)
     tbl = rng.permutation(np.arange(1, P))[:B * NB].reshape(B, NB)
     if base is None:
         base = rng.randint(0, NB * page - T + 1, size=B)
@@ -373,7 +373,7 @@ def test_paged_prefill_oracle_matches_dense_causal():
     v = jax.random.normal(ks[2], (B, TT, KV, D), jnp.float32)
     rng = np.random.RandomState(3)
     P = 1 + B * NB
-    kp = np.zeros((1, P, page, KV, D), np.float32)
+    kp = np.zeros((1, P, KV, page, D), np.float32)
     vp = np.zeros_like(kp)
     tbl = np.zeros((B, NB), np.int32)
     pages = 1 + rng.permutation(B * NB)
@@ -381,8 +381,10 @@ def test_paged_prefill_oracle_matches_dense_causal():
         for j in range(NB):
             pg = pages[b * NB + j]
             tbl[b, j] = pg
-            kp[0, pg] = np.asarray(k)[b, j * page:(j + 1) * page]
-            vp[0, pg] = np.asarray(v)[b, j * page:(j + 1) * page]
+            kp[0, pg] = np.asarray(k)[b, j * page:(j + 1) * page].swapaxes(
+                0, 1)
+            vp[0, pg] = np.asarray(v)[b, j * page:(j + 1) * page].swapaxes(
+                0, 1)
     base = jnp.asarray([7, 2], jnp.int32)          # mid-page, ragged
     new = base + jnp.asarray([5, 3], jnp.int32)
     got = paged_prefill_attention_ref(q, jnp.asarray(kp), jnp.asarray(vp),
@@ -432,8 +434,8 @@ def test_prefill_step_paged_matches_sequential_decode(small_model):
     for i in range(B):
         for t in range(grants[i]):
             pg, off = tbl[i, t // page], t % page
-            np.testing.assert_array_equal(k_seq[:, pg, off],
-                                          k_chunk[:, pg, off])
+            np.testing.assert_array_equal(k_seq[:, pg, :, off],
+                                          k_chunk[:, pg, :, off])
 
 
 @pytest.mark.parametrize("lane", [True, False])
@@ -499,7 +501,7 @@ def test_paged_oracle_matches_dense_on_packed_pages():
     # pack slot b's rows into pages 1 + b*NB + j (order scrambled per slot)
     rng = np.random.RandomState(3)
     P = 1 + B * NB
-    kp = np.zeros((1, P, page, KV, D), np.float32)
+    kp = np.zeros((1, P, KV, page, D), np.float32)
     vp = np.zeros_like(kp)
     tbl = np.zeros((B, NB), np.int32)
     pages = 1 + rng.permutation(B * NB)
@@ -507,8 +509,10 @@ def test_paged_oracle_matches_dense_on_packed_pages():
         for j in range(NB):
             pg = pages[b * NB + j]
             tbl[b, j] = pg
-            kp[0, pg] = np.asarray(k)[b, j * page:(j + 1) * page]
-            vp[0, pg] = np.asarray(v)[b, j * page:(j + 1) * page]
+            kp[0, pg] = np.asarray(k)[b, j * page:(j + 1) * page].swapaxes(
+                0, 1)
+            vp[0, pg] = np.asarray(v)[b, j * page:(j + 1) * page].swapaxes(
+                0, 1)
     lens = jnp.asarray([T - 3, page + 1], jnp.int32)      # ragged, page !| len
     got = paged_decode_attention_ref(q, jnp.asarray(kp), jnp.asarray(vp),
                                      jnp.asarray(tbl), lens)
@@ -902,7 +906,7 @@ def test_cow_page_copy_census_scales_with_pages(dtype):
     L, page, KV, hd = 4, 16, 2, 16
 
     def census(P, n):
-        pool = jax.ShapeDtypeStruct((L, P, page, KV, hd), dtype)
+        pool = jax.ShapeDtypeStruct((L, P, KV, page, hd), dtype)
         idx = jax.ShapeDtypeStruct((n,), jnp.int32)
         compiled = jax.jit(_copy_pages, donate_argnums=(0,)).lower(
             pool, idx, idx).compile()
@@ -930,10 +934,10 @@ def test_cow_page_copy_census_scales_with_pages(dtype):
     # stays page-scaled and pool-independent
     def cow_append(pool, dst, src, kv_new, row):
         pool = _copy_pages(pool, dst, src)
-        return pool.at[:, dst[0], row].set(kv_new)
+        return pool.at[:, dst[0], :, row].set(kv_new)
 
     def fused_census(P):
-        pool = jax.ShapeDtypeStruct((L, P, page, KV, hd), dtype)
+        pool = jax.ShapeDtypeStruct((L, P, KV, page, hd), dtype)
         idx = jax.ShapeDtypeStruct((1,), jnp.int32)
         kvn = jax.ShapeDtypeStruct((L, KV, hd), dtype)
         row = jax.ShapeDtypeStruct((), jnp.int32)

@@ -43,9 +43,9 @@ def test_quantize_roundtrip_bound():
     x = jax.random.normal(jax.random.key(0), (5, 3, 2, 16), jnp.float32)
     q, s = quantize_rows(x)
     assert q.dtype == jnp.int8 and s.dtype == jnp.float32
-    assert q.shape == x.shape and s.shape == x.shape[:-1]
+    assert q.shape == x.shape and s.shape == x.shape[:-1] + (1,)
     err = np.abs(np.asarray(dequantize_rows(q, s)) - np.asarray(x))
-    assert (err <= np.asarray(s)[..., None] * 0.5 + 1e-7).all()
+    assert (err <= np.asarray(s) * 0.5 + 1e-7).all()
     # the row absmax is representable exactly (hits +-127)
     assert (np.abs(np.asarray(q)).max(axis=-1) == QMAX).all()
 
@@ -67,7 +67,7 @@ def test_bf16_rows_roundtrip_through_f32():
     q, s = quantize_rows(x)
     err = np.abs(np.asarray(dequantize_rows(q, s))
                  - np.asarray(x, np.float32))
-    assert (err <= np.asarray(s)[..., None] * 0.5 + 1e-7).all()
+    assert (err <= np.asarray(s) * 0.5 + 1e-7).all()
 
 
 # ---------------------------------------------------------------------------
@@ -81,8 +81,8 @@ def _quantized_paged_case(seed, B, H, KV, D, page, NB, L, extra_pages=3):
     P = B * NB + extra_pages
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, 1, H, D), jnp.float32)
-    kq, ksc = quantize_rows(jax.random.normal(ks[1], (L, P, page, KV, D)))
-    vq, vsc = quantize_rows(jax.random.normal(ks[2], (L, P, page, KV, D)))
+    kq, ksc = quantize_rows(jax.random.normal(ks[1], (L, P, KV, page, D)))
+    vq, vsc = quantize_rows(jax.random.normal(ks[2], (L, P, KV, page, D)))
     tbl = rng.permutation(np.arange(1, P))[:B * NB].reshape(B, NB)
     lens = rng.randint(1, NB * page + 1, size=B)
     layer = rng.randint(0, L)
@@ -131,8 +131,8 @@ def test_quantized_paged_prefill_matches_dequant_oracle(B, T, H, KV, D,
     P = B * NB + 3
     ks = jax.random.split(jax.random.key(B + T + H), 3)
     q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
-    kq, ksc = quantize_rows(jax.random.normal(ks[1], (L, P, page, KV, D)))
-    vq, vsc = quantize_rows(jax.random.normal(ks[2], (L, P, page, KV, D)))
+    kq, ksc = quantize_rows(jax.random.normal(ks[1], (L, P, KV, page, D)))
+    vq, vsc = quantize_rows(jax.random.normal(ks[2], (L, P, KV, page, D)))
     tbl = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
                       .reshape(B, NB), jnp.int32)
     base = rng.randint(0, NB * page - T + 1, size=B)
@@ -174,8 +174,8 @@ def test_quantized_attention_drift_bounded():
     B, H, KV, D, page, NB, L, P = 2, 4, 2, 32, 8, 4, 2, 12
     ks = jax.random.split(jax.random.key(5), 3)
     q = jax.random.normal(ks[0], (B, 1, H, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (L, P, page, KV, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (L, P, page, KV, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, P, KV, page, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, P, KV, page, D), jnp.float32)
     kq, ksc = quantize_rows(kp)
     vq, vsc = quantize_rows(vp)
     tbl = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
@@ -211,8 +211,8 @@ def test_int8_pool_decls_and_page_bytes(int8_harness):
     assert kv.quantized
     assert kv.k.dtype == jnp.int8 and kv.v.dtype == jnp.int8
     assert kv.k_scale.dtype == jnp.float32
-    assert kv.k_scale.shape == kv.k.shape[:-1]
-    L, _, page, KV, hd = kv.k.shape
+    assert kv.k_scale.shape == kv.k.shape[:-1] + (1,)
+    L, _, KV, page, hd = kv.k.shape
     assert kv.page_bytes == 2 * L * page * KV * (hd + 4)
 
 
@@ -235,8 +235,8 @@ def test_int8_cow_preserves_quantized_rows_and_scales(int8_harness):
     assert 0 < n_shared < pe.kv.page
     shared = [p for p in range(1, pe.kv.num_pages) if pe.kv.refcount[p] > 1]
     assert shared, "admission did not map a page into both tables"
-    before = {p: (np.asarray(pe.kv.k)[:, p, :n_shared].copy(),
-                  np.asarray(pe.kv.k_scale)[:, p, :n_shared].copy())
+    before = {p: (np.asarray(pe.kv.k)[:, p, :, :n_shared].copy(),
+                  np.asarray(pe.kv.k_scale)[:, p, :, :n_shared].copy())
               for p in shared}
     pe.step()                             # both append into the shared page
     assert pe.kv.cow_copies > 0
@@ -244,10 +244,10 @@ def test_int8_cow_preserves_quantized_rows_and_scales(int8_harness):
     after_s = np.asarray(pe.kv.k_scale)
     for p, (rows, scales) in before.items():
         np.testing.assert_array_equal(
-            rows, after_k[:, p, :n_shared],
+            rows, after_k[:, p, :, :n_shared],
             err_msg=f"write into shared page {p} reached shared int8 rows")
         np.testing.assert_array_equal(
-            scales, after_s[:, p, :n_shared],
+            scales, after_s[:, p, :, :n_shared],
             err_msg=f"write into shared page {p} reached shared scales")
     res = pe.run()
     pe.kv.check()
@@ -296,8 +296,8 @@ def test_quantized_cow_copy_census_page_scaled():
     L, page, KV, hd = 4, 16, 2, 16
 
     def census(P, n):
-        pool = jax.ShapeDtypeStruct((L, P, page, KV, hd), jnp.int8)
-        scale = jax.ShapeDtypeStruct((L, P, page, KV), jnp.float32)
+        pool = jax.ShapeDtypeStruct((L, P, KV, page, hd), jnp.int8)
+        scale = jax.ShapeDtypeStruct((L, P, KV, page, 1), jnp.float32)
         idx = jax.ShapeDtypeStruct((n,), jnp.int32)
         compiled = jax.jit(_copy_pages_quant,
                            donate_argnums=(0, 1, 2, 3)).lower(

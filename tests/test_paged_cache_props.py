@@ -611,13 +611,14 @@ def test_cow_preserves_shared_rows(harness):
     assert 0 < n_shared < pe.kv.page      # partial-page share
     shared = [p for p in range(1, pe.kv.num_pages) if pe.kv.refcount[p] > 1]
     assert shared, "admission did not map a page into both tables"
-    before = {p: np.asarray(pe.kv.k)[:, p, :n_shared].copy() for p in shared}
+    before = {p: np.asarray(pe.kv.k)[:, p, :, :n_shared].copy()
+              for p in shared}
     pe.step()                             # both append into the shared page
     assert pe.kv.cow_copies > 0
     after = np.asarray(pe.kv.k)
     for p, rows in before.items():
         np.testing.assert_array_equal(
-            rows, after[:, p, :n_shared],
+            rows, after[:, p, :, :n_shared],
             err_msg=f"write into shared page {p} reached shared rows")
     res = pe.run()                        # drain: outputs stay exact
     for rid in (rid_a, rid_b):

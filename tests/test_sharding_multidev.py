@@ -24,11 +24,8 @@ from repro.core.hlo_counters import census_from_compiled
 
 out = {}
 
-try:
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-except (AttributeError, TypeError):            # jax < 0.5: no AxisType
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = MeshRules(batch_axes=("data",), fsdp_axes=("data",),
                   model_axis="model")
 

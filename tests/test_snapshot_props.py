@@ -34,6 +34,7 @@ Explicit seeded fuzz loops (no hypothesis in the container image);
 assertion messages carry ``[repro: schedule_seed=N fault_seed=M]``.
 """
 import dataclasses
+import json
 import os
 
 import numpy as np
@@ -363,6 +364,27 @@ def test_fingerprint_mismatch_typed(harness, tmp_path):
         snap.restore_engine(other, path)
     msg = str(ei.value)
     assert "max_batch" in msg and "max_seq" in msg
+
+
+def test_token_major_version_refused(harness, tmp_path):
+    """A version-1 file holds token-major pools: the reader refuses it
+    with the typed mismatch error before touching its arrays."""
+    model, params = harness
+    pe = _mk(model, params, None)
+    pe.submit(np.arange(3, dtype=np.int32), 3)
+    pe.step()
+    path = snap.save_snapshot(pe, snap.snapshot_path(str(tmp_path), 1))
+    blob = open(path, "rb").read()
+    off = len(snap.MAGIC)
+    hlen = int.from_bytes(blob[off:off + 8], "little")
+    header = json.loads(blob[off + 8:off + 8 + hlen])
+    header["version"] = 1
+    old = json.dumps(header).encode("utf-8")
+    with open(path, "wb") as f:
+        f.write(snap.MAGIC + len(old).to_bytes(8, "little") + old
+                + blob[off + 8 + hlen:])
+    with pytest.raises(snap.SnapshotMismatchError, match="version 1"):
+        snap.restore_engine(_mk(model, params, None), path)
 
 
 # ---------------------------------------------------------------------------
