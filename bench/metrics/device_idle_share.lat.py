@@ -1,0 +1,4 @@
+"""device_idle_share.lat (%; layer: device): 100 x (1 - the union of the
+intervals in which an op ran on the device / the traced window), in a
+latency cell."""
+from bench.record import idle_share as read  # noqa: F401
