@@ -18,9 +18,10 @@ speculative|all``):
     position, per-slot start windows, prompts prefilled BY DECODE one
     token per shared step), reconstructed here as a measurement-only
     driver so the ``ragged_paged_speedup`` trajectory stays comparable
-    across PRs.  Page-pool utilization and row occupancy are recorded PER
-    TICK from the engine's traces and reduced to mean/max across the
-    drive's ticks (the old numbers sampled only the end state); the
+    across PRs.  Page-pool utilization (the engine's per-tick trace) and
+    row occupancy (sampled by the driver at the same mid-tick point) are
+    recorded PER TICK and reduced to mean/max across the drive's ticks
+    (the old numbers sampled only the end state); the
     utilization stats come from a second, untimed drive with periodic
     defrag so they describe the compacted pool.
   * shared-prefix — a common system prompt across all requests (3x batch
@@ -243,29 +244,47 @@ def _drive(engine, reqs, defrag_every: int = 0) -> Dict[str, float]:
     """Submit a workload against a warm engine and time the drain, with an
     optional periodic defrag.  Tokens/joins/utilization are counted for
     THIS drive's ticks only (counters and traces accumulate across drives
-    — the warm-up run must not leak into the timed window)."""
+    — the warm-up run must not leak into the timed window).  Each working
+    tick's device calls and upload bytes are the deltas of the engine's
+    cumulative counters across it.  Row occupancy is sampled where the
+    engine takes its per-tick utilization (mid-tick, after the appends and
+    before finishes release pages), by riding on that call, when the
+    engine records pool traces."""
     joins0, ticks0 = engine.joins, engine.steps_run
     stalls0 = engine.stalls
     appended0, shared0 = engine.tokens_appended, engine.shared_tokens
     cow0 = engine.kv.cow_copies
     rids = [engine.submit(p, mnt) for p, mnt in reqs]
+    disp: List[int] = []
+    upload: List[int] = []
+    occ: List[float] = []
+    kv = engine.kv
+    utilization = kv.utilization
+
+    def sampled():
+        occ.append(kv.occupancy())
+        return utilization()
+
+    kv.utilization = sampled
     t0 = time.perf_counter()
     while engine.busy:
+        d0, u0, s0 = engine.dispatches, engine.upload_bytes, engine.steps_run
         engine.step()
+        if engine.steps_run > s0:                    # a working tick
+            disp.append(engine.dispatches - d0)
+            upload.append(engine.upload_bytes - u0)
         if defrag_every and (engine.steps_run - ticks0) % defrag_every == 0:
             engine.defrag()
     dt = time.perf_counter() - t0
+    del kv.utilization
     results = engine.results
     n_tok = sum(len(results[r]) for r in rids)
     util = engine.util_trace[ticks0:]
-    occ = engine.occupancy_trace[ticks0:]
     appended = engine.tokens_appended - appended0
     shared = engine.shared_tokens - shared0
-    # tick-overhead traces for THIS drive's ticks (the full traces index
-    # by tick, matching steps_run, whether or not pool traces are on)
+    # the host-ms trace for THIS drive's ticks (it indexes by working
+    # tick, matching steps_run)
     host_ms = engine.host_ms_trace[ticks0:]
-    disp = engine.dispatch_trace[ticks0:]
-    upload = engine.upload_trace[ticks0:]
     # a STEADY tick is one dispatch AND only the irreducible B-int
     # feed/grant upload — a forced-prefill tick can also run one dispatch
     # but carries (chunk, B) forced arrays, so classify on both
@@ -908,8 +927,9 @@ def run_speculative() -> Dict[str, float]:
     sp, pl = stats["spec"], stats["plain"]
     pe = engines["spec"]
     identity = outs["spec"] is not None and outs["spec"] == outs["plain"]
-    ddisp = pe.draft_dispatch_trace
-    vdisp = pe.verify_dispatch_trace
+    # per working tick over the engine's life (warm-up, timed and
+    # identity drives alike)
+    busy_ticks = max(1, pe.steps_run)
     return {
         "speculative_tokens": sp["tokens"],
         "speculative_tokens_per_s": sp["tokens_per_s"],
@@ -922,10 +942,10 @@ def run_speculative() -> Dict[str, float]:
         "speculative_ticks": sp["ticks"],
         "speculative_ticks_plain": pl["ticks"],
         "speculative_tokens_per_tick": sp["tokens"] / max(sp["ticks"], 1.0),
-        "speculative_draft_dispatches_per_tick": (float(np.mean(ddisp))
-                                                  if ddisp else 0.0),
-        "speculative_verify_dispatches_per_tick": (float(np.mean(vdisp))
-                                                   if vdisp else 0.0),
+        "speculative_draft_dispatches_per_tick":
+            pe.draft_dispatches / busy_ticks,
+        "speculative_verify_dispatches_per_tick":
+            pe.verify_dispatches / busy_ticks,
         "speculative_trunc_tokens": float(pe.spec_trunc_tokens),
     }
 

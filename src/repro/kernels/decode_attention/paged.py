@@ -217,5 +217,6 @@ def paged_decode_attention_fwd(q: jax.Array, k_pool: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(kvl, tbl, lay, qg, *([k_pool] * P), *([v_pool] * P), *scale_args)
     return out.reshape(B, 1, H, D)

@@ -228,6 +228,7 @@ def paged_prefill_attention_fwd(q: jax.Array, k_pool: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, TG, D), q.dtype),
         interpret=interpret,
+        name="ragged_prefill_attention",
     )(base, kvl, tbl, lay, qg, *([k_pool] * P), *([v_pool] * P),
       *scale_args)
     out = out.reshape(B, KV, T, G, D).transpose(0, 2, 1, 3, 4)

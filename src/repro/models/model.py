@@ -210,7 +210,8 @@ class Model:
         Returns (next_tok (B,) int32, cache, key)."""
         logits, cache = self.prefill_step_paged(params, tokens, cache,
                                                 grants)
-        nxt, key = sample_token(logits, key, temperature)
+        with jax.named_scope("sample"):
+            nxt, key = sample_token(logits, key, temperature)
         return nxt, cache, key
 
     def verify_many_paged(self, params, tokens, cache, grants):
@@ -277,9 +278,10 @@ class Model:
             tok, cache, key = carry
             f_tok, f_mask, act = xs
             logits, cache = self.decode_step_paged(params, tok, cache, act)
-            nxt, key = sample_token(logits, key, temperature)
-            nxt = jnp.where(f_mask, f_tok, nxt)
-            nxt = jnp.where(act, nxt, tok[:, 0])
+            with jax.named_scope("sample"):
+                nxt, key = sample_token(logits, key, temperature)
+                nxt = jnp.where(f_mask, f_tok, nxt)
+                nxt = jnp.where(act, nxt, tok[:, 0])
             return (nxt[:, None], cache, key), nxt
 
         (_, cache, key), toks = jax.lax.scan(

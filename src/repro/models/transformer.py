@@ -291,45 +291,55 @@ def attn_decode_paged(h, p, cfg: ArchConfig, rope, k_pool, v_pool, layer,
 
     With quantized pools (k_scale/v_scale not None) the appended row is
     int8-quantized per KV head and the row's f32 scale lands at the same
-    (layer, page, head, row) address — the scale travels with its page."""
-    hn = apply_norm(h, p["ln1"], cfg)
+    (layer, page, head, row) address — the scale travels with its page.
+
+    The step's work is named by ``jax.named_scope`` (``qkv``, ``kv_write``,
+    ``attn``, ``out_proj``), so each device op's ``op_name`` says which
+    part of the layer it belongs to."""
     a = p["attn"]
-    q, k, v = _qkv(hn, a, cfg, rope, decode=True)
+    with jax.named_scope("qkv"):
+        hn = apply_norm(h, p["ln1"], cfg)
+        q, k, v = _qkv(hn, a, cfg, rope, decode=True)
     B = h.shape[0]
-    page = k_pool.shape[3]
-    nb = table.shape[1]
-    blk = jnp.minimum(lengths // page, nb - 1)
-    phys = jnp.where(active, table[jnp.arange(B), blk], 0)
-    off = lengths % page
-    at = _row_index(layer, phys, off, k.shape[2])
-    if k_scale is not None:                # quantize the appended row
-        kq, ks = quantize_rows(k[:, 0])    # (B, KV, hd) int8, (B, KV, 1) f32
-        vq, vs = quantize_rows(v[:, 0])
-        k_pool = k_pool.at[at].set(kq)
-        v_pool = v_pool.at[at].set(vq)
-        k_scale = k_scale.at[at].set(ks)
-        v_scale = v_scale.at[at].set(vs)
-        k_scale = constrain(k_scale, None, "cache_seq", None, None, None)
-        v_scale = constrain(v_scale, None, "cache_seq", None, None, None)
-    else:
-        k_pool = k_pool.at[at].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[at].set(v[:, 0].astype(v_pool.dtype))
-    # keep the pool page-sharded through the in-place update
-    k_pool = constrain(k_pool, None, "cache_seq", None, None, None)
-    v_pool = constrain(v_pool, None, "cache_seq", None, None, None)
-    kv_len = lengths + 1
-    if cfg.attention_impl == "pallas":
-        from repro.kernels.decode_attention.ops import paged_decode_attention
-        out = paged_decode_attention(q, k_pool, v_pool, table, kv_len, layer,
-                                     pages_per_step=cfg.pages_per_step,
-                                     k_scale=k_scale, v_scale=v_scale)
-    else:
-        from repro.kernels.decode_attention.ref import (
-            paged_decode_attention_ref)
-        out = paged_decode_attention_ref(q, k_pool, v_pool, table, kv_len,
-                                         layer, k_scale=k_scale,
-                                         v_scale=v_scale)
-    out = dense(out.reshape(B, 1, -1), a["wo"])
+    with jax.named_scope("kv_write"):
+        page = k_pool.shape[3]
+        nb = table.shape[1]
+        blk = jnp.minimum(lengths // page, nb - 1)
+        phys = jnp.where(active, table[jnp.arange(B), blk], 0)
+        off = lengths % page
+        at = _row_index(layer, phys, off, k.shape[2])
+        if k_scale is not None:            # quantize the appended row
+            kq, ks = quantize_rows(k[:, 0])  # (B, KV, hd) int8, (B, KV, 1)
+            vq, vs = quantize_rows(v[:, 0])
+            k_pool = k_pool.at[at].set(kq)
+            v_pool = v_pool.at[at].set(vq)
+            k_scale = k_scale.at[at].set(ks)
+            v_scale = v_scale.at[at].set(vs)
+            k_scale = constrain(k_scale, None, "cache_seq", None, None, None)
+            v_scale = constrain(v_scale, None, "cache_seq", None, None, None)
+        else:
+            k_pool = k_pool.at[at].set(k[:, 0].astype(k_pool.dtype))
+            v_pool = v_pool.at[at].set(v[:, 0].astype(v_pool.dtype))
+        # keep the pool page-sharded through the in-place update
+        k_pool = constrain(k_pool, None, "cache_seq", None, None, None)
+        v_pool = constrain(v_pool, None, "cache_seq", None, None, None)
+    with jax.named_scope("attn"):
+        kv_len = lengths + 1
+        if cfg.attention_impl == "pallas":
+            from repro.kernels.decode_attention.ops import (
+                paged_decode_attention)
+            out = paged_decode_attention(q, k_pool, v_pool, table, kv_len,
+                                         layer,
+                                         pages_per_step=cfg.pages_per_step,
+                                         k_scale=k_scale, v_scale=v_scale)
+        else:
+            from repro.kernels.decode_attention.ref import (
+                paged_decode_attention_ref)
+            out = paged_decode_attention_ref(q, k_pool, v_pool, table,
+                                             kv_len, layer, k_scale=k_scale,
+                                             v_scale=v_scale)
+    with jax.named_scope("out_proj"):
+        out = dense(out.reshape(B, 1, -1), a["wo"])
     return out, k_pool, v_pool, k_scale, v_scale
 
 
@@ -354,51 +364,57 @@ def attn_prefill_paged(h, p, cfg: ArchConfig, rope, k_pool, v_pool, layer,
     verbatim: the scheduler privatizes any shared page the chunk's rows
     would touch (and grants prefill in page-aligned token blocks) BEFORE
     the tick, so a chunk scatter can never reach rows another block table
-    still exposes."""
-    hn = apply_norm(h, p["ln1"], cfg)
+    still exposes.  Named scopes as in ``attn_decode_paged``."""
     a = p["attn"]
-    q, k, v = _qkv(hn, a, cfg, rope, decode=True)
+    with jax.named_scope("qkv"):
+        hn = apply_norm(h, p["ln1"], cfg)
+        q, k, v = _qkv(hn, a, cfg, rope, decode=True)
     B, T, _ = h.shape
-    page = k_pool.shape[3]
-    nb = table.shape[1]
-    tok_pos = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    blk = jnp.minimum(tok_pos // page, nb - 1)
-    live = tok_pos < new_len[:, None]                       # granted rows
-    phys = jnp.where(live, jnp.take_along_axis(table, blk, axis=1), 0)
-    off = (tok_pos % page).reshape(B * T)
-    phys = phys.reshape(B * T)
-    KV, hd = k.shape[2], k.shape[3]
-    at = _row_index(layer, phys, off, KV)
-    if k_scale is not None:                # quantize all the chunk's rows
-        kq, ks = quantize_rows(k.reshape(B * T, KV, hd))
-        vq, vs = quantize_rows(v.reshape(B * T, KV, hd))
-        k_pool = k_pool.at[at].set(kq)
-        v_pool = v_pool.at[at].set(vq)
-        k_scale = k_scale.at[at].set(ks)
-        v_scale = v_scale.at[at].set(vs)
-        k_scale = constrain(k_scale, None, "cache_seq", None, None, None)
-        v_scale = constrain(v_scale, None, "cache_seq", None, None, None)
-    else:
-        k_pool = k_pool.at[at].set(
-            k.reshape(B * T, KV, hd).astype(k_pool.dtype))
-        v_pool = v_pool.at[at].set(
-            v.reshape(B * T, KV, hd).astype(v_pool.dtype))
-    # keep the pool page-sharded through the in-place update
-    k_pool = constrain(k_pool, None, "cache_seq", None, None, None)
-    v_pool = constrain(v_pool, None, "cache_seq", None, None, None)
-    if cfg.attention_impl == "pallas":
-        from repro.kernels.decode_attention.ops import paged_prefill_attention
-        out = paged_prefill_attention(q, k_pool, v_pool, table, base,
-                                      new_len, layer,
-                                      pages_per_step=cfg.pages_per_step,
-                                      k_scale=k_scale, v_scale=v_scale)
-    else:
-        from repro.kernels.decode_attention.ref import (
-            paged_prefill_attention_ref)
-        out = paged_prefill_attention_ref(q, k_pool, v_pool, table, base,
-                                          new_len, layer, k_scale=k_scale,
-                                          v_scale=v_scale)
-    out = dense(out.reshape(B, T, -1), a["wo"])
+    with jax.named_scope("kv_write"):
+        page = k_pool.shape[3]
+        nb = table.shape[1]
+        tok_pos = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        blk = jnp.minimum(tok_pos // page, nb - 1)
+        live = tok_pos < new_len[:, None]                   # granted rows
+        phys = jnp.where(live, jnp.take_along_axis(table, blk, axis=1), 0)
+        off = (tok_pos % page).reshape(B * T)
+        phys = phys.reshape(B * T)
+        KV, hd = k.shape[2], k.shape[3]
+        at = _row_index(layer, phys, off, KV)
+        if k_scale is not None:            # quantize all the chunk's rows
+            kq, ks = quantize_rows(k.reshape(B * T, KV, hd))
+            vq, vs = quantize_rows(v.reshape(B * T, KV, hd))
+            k_pool = k_pool.at[at].set(kq)
+            v_pool = v_pool.at[at].set(vq)
+            k_scale = k_scale.at[at].set(ks)
+            v_scale = v_scale.at[at].set(vs)
+            k_scale = constrain(k_scale, None, "cache_seq", None, None, None)
+            v_scale = constrain(v_scale, None, "cache_seq", None, None, None)
+        else:
+            k_pool = k_pool.at[at].set(
+                k.reshape(B * T, KV, hd).astype(k_pool.dtype))
+            v_pool = v_pool.at[at].set(
+                v.reshape(B * T, KV, hd).astype(v_pool.dtype))
+        # keep the pool page-sharded through the in-place update
+        k_pool = constrain(k_pool, None, "cache_seq", None, None, None)
+        v_pool = constrain(v_pool, None, "cache_seq", None, None, None)
+    with jax.named_scope("attn"):
+        if cfg.attention_impl == "pallas":
+            from repro.kernels.decode_attention.ops import (
+                paged_prefill_attention)
+            out = paged_prefill_attention(q, k_pool, v_pool, table, base,
+                                          new_len, layer,
+                                          pages_per_step=cfg.pages_per_step,
+                                          k_scale=k_scale, v_scale=v_scale)
+        else:
+            from repro.kernels.decode_attention.ref import (
+                paged_prefill_attention_ref)
+            out = paged_prefill_attention_ref(q, k_pool, v_pool, table,
+                                              base, new_len, layer,
+                                              k_scale=k_scale,
+                                              v_scale=v_scale)
+    with jax.named_scope("out_proj"):
+        out = dense(out.reshape(B, T, -1), a["wo"])
     return out, k_pool, v_pool, k_scale, v_scale
 
 
@@ -416,13 +432,15 @@ def decode_ffn(h, p, cfg: ArchConfig):
     """The post-attention residual of a decode/prefill step: routed MoE
     (aux dropped — no load-balance loss at inference) or the dense FFN.
     Shared by the dense decode, paged decode and paged prefill bodies so
-    the lanes cannot silently diverge."""
-    if cfg.n_experts:
-        m = p["moe"]
-        hn = apply_norm(h, p["ln2"], cfg)
-        o, _ = moe_ffn(hn, m["router"], m["w1"], m["w2"], m.get("w3"), cfg)
-        return h + o
-    return h + ffn_apply(h, p, cfg)
+    the lanes cannot silently diverge.  Named scope ``ffn``."""
+    with jax.named_scope("ffn"):
+        if cfg.n_experts:
+            m = p["moe"]
+            hn = apply_norm(h, p["ln2"], cfg)
+            o, _ = moe_ffn(hn, m["router"], m["w1"], m["w2"], m.get("w3"),
+                           cfg)
+            return h + o
+        return h + ffn_apply(h, p, cfg)
 
 
 def dense_block(h, p, cfg: ArchConfig, rope):
@@ -640,24 +658,32 @@ def lm_decode_paged(params, cfg: ArchConfig, tokens, cache, active):
     ``length`` — rope positions are per-slot (request-relative, starting at
     0 on the slot's own pages), appends go to the slot's own pages via the
     block table, and inactive slots write only the reserved null page 0
-    without advancing.  Decoder-only attention LMs only."""
+    without advancing.  Decoder-only attention LMs only.
+
+    Every op of the step sits in one named scope (``embed``, ``qkv``,
+    ``kv_write``, ``attn``, ``out_proj``, ``ffn``, ``head``), which the
+    compiler carries into each instruction's ``op_name``: a device trace
+    of the step is read per part of the layer by those names."""
     if cfg.mamba_version or cfg.is_encoder_decoder:
         raise ValueError("paged decode requires a decoder-only attention LM")
     lengths = cache["length"]
     table = cache["table"]
     B = tokens.shape[0]
-    positions = lengths[:, None]                       # per-slot positions
-    if cfg.mrope_sections:
-        positions = jnp.broadcast_to(positions[None], (3, B, 1))
-    rope = _rope(cfg, positions)
-    h = _embed_in(params, cfg, tokens)
+    with jax.named_scope("qkv"):
+        positions = lengths[:, None]                   # per-slot positions
+        if cfg.mrope_sections:
+            positions = jnp.broadcast_to(positions[None], (3, B, 1))
+        rope = _rope(cfg, positions)
+    with jax.named_scope("embed"):
+        h = _embed_in(params, cfg, tokens)
 
     def body(carry, p):
         h, k_all, v_all, ks_all, vs_all, li = carry
         out, k_all, v_all, ks_all, vs_all = attn_decode_paged(
             h, p, cfg, rope, k_all, v_all, li, table, lengths, active,
             k_scale=ks_all, v_scale=vs_all)
-        h = h + out
+        with jax.named_scope("out_proj"):
+            h = h + out
         h = decode_ffn(h, p, cfg)
         return (h, k_all, v_all, ks_all, vs_all, li + 1), None
 
@@ -670,7 +696,8 @@ def lm_decode_paged(params, cfg: ArchConfig, tokens, cache, active):
                      length=lengths + active.astype(jnp.int32))
     if ks is not None:
         new_cache.update(k_scale=ks, v_scale=vs)
-    return _logits_exact(params, cfg, h)[:, 0], new_cache
+    with jax.named_scope("head"):
+        return _logits_exact(params, cfg, h)[:, 0], new_cache
 
 
 def lm_prefill_paged(params, cfg: ArchConfig, tokens, cache, grants,
@@ -701,18 +728,22 @@ def lm_prefill_paged(params, cfg: ArchConfig, tokens, cache, grants,
     B, T = tokens.shape
     grants = jnp.asarray(grants, jnp.int32)
     new_len = lengths + grants
-    positions = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    if cfg.mrope_sections:
-        positions = jnp.broadcast_to(positions[None], (3, B, T))
-    rope = _rope(cfg, positions)
-    h = _embed_in(params, cfg, tokens)
+    with jax.named_scope("qkv"):
+        positions = (lengths[:, None]
+                     + jnp.arange(T, dtype=jnp.int32)[None, :])
+        if cfg.mrope_sections:
+            positions = jnp.broadcast_to(positions[None], (3, B, T))
+        rope = _rope(cfg, positions)
+    with jax.named_scope("embed"):
+        h = _embed_in(params, cfg, tokens)
 
     def body(carry, p):
         h, k_all, v_all, ks_all, vs_all, li = carry
         out, k_all, v_all, ks_all, vs_all = attn_prefill_paged(
             h, p, cfg, rope, k_all, v_all, li, table, lengths, new_len,
             k_scale=ks_all, v_scale=vs_all)
-        h = h + out
+        with jax.named_scope("out_proj"):
+            h = h + out
         h = decode_ffn(h, p, cfg)
         return (h, k_all, v_all, ks_all, vs_all, li + 1), None
 
@@ -722,12 +753,14 @@ def lm_prefill_paged(params, cfg: ArchConfig, tokens, cache, grants,
     new_cache = dict(cache, k=k, v=v, length=new_len)
     if ks is not None:
         new_cache.update(k_scale=ks, v_scale=vs)
-    if unembed_all:
-        return _logits_exact(params, cfg, h), new_cache     # (B, T, V)
-    # last granted position per slot (grants==0 -> clipped; caller ignores)
-    last = jnp.maximum(grants - 1, 0)[:, None, None]
-    h_last = jnp.take_along_axis(h, last, axis=1)           # (B, 1, d)
-    return _logits_exact(params, cfg, h_last)[:, 0], new_cache
+    with jax.named_scope("head"):
+        if unembed_all:
+            return _logits_exact(params, cfg, h), new_cache  # (B, T, V)
+        # last granted position per slot (grants==0 -> clipped; caller
+        # ignores)
+        last = jnp.maximum(grants - 1, 0)[:, None, None]
+        h_last = jnp.take_along_axis(h, last, axis=1)        # (B, 1, d)
+        return _logits_exact(params, cfg, h_last)[:, 0], new_cache
 
 
 def lm_verify_paged(params, cfg: ArchConfig, tokens, cache, grants):

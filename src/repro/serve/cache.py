@@ -58,6 +58,13 @@ import numpy as np
 
 from repro.models.model import Model
 
+# the cache's cumulative counters (PagedEngine.counters reads them; a
+# snapshot carries them over a restore)
+COUNTERS = (
+    "cow_copies", "cow_bytes", "cow_dispatches", "shared_pages",
+    "retained_hits", "retained_hit_tokens", "retained_reclaimed_pages",
+    "retained_dropped")
+
 # rolling-hash prefix digests — shared with the engine's live-donor index
 # (serve/engine.py) so a retained entry and a live slot hash identically
 _HASH_MUL = 1_000_003
@@ -112,6 +119,13 @@ def _copy_pages(pool, dst, src):
     return pool.at[layers, dst[None, :]].set(pool[layers, src[None, :]])
 
 
+def _compact_pages(pool, perm):
+    """Defrag's renumbering of a stacked pool: page ``perm[j]`` moves to
+    page ``j`` (its own jit name, so a trace tells it from the engine's
+    cells)."""
+    return pool[:, perm]
+
+
 def _copy_pages_both(k, v, dst, src):
     """COW copies k AND v in one dispatch (both pools donated)."""
     return _copy_pages(k, dst, src), _copy_pages(v, dst, src)
@@ -156,8 +170,7 @@ class PagedKVCache:
         self.owned: List[List[int]] = [[] for _ in range(max_batch)]
         self.refcount = np.zeros((self.num_pages,), np.int32)
         self.free: List[int] = list(range(self.num_pages - 1, 0, -1))
-        self._gather = jax.jit(lambda pool, perm: pool[:, perm],
-                               donate_argnums=(0,))
+        self._gather = jax.jit(_compact_pages, donate_argnums=(0,))
         self._copy = jax.jit(_copy_pages_both, donate_argnums=(0, 1))
         self._copy_quant = jax.jit(_copy_pages_quant,
                                    donate_argnums=(0, 1, 2, 3))
@@ -295,13 +308,15 @@ class PagedKVCache:
         Returns the pages copied."""
         if not self._pending_cow:
             return 0
-        dst = jnp.asarray([p[0] for p in self._pending_cow], jnp.int32)
-        src = jnp.asarray([p[1] for p in self._pending_cow], jnp.int32)
-        if self.quantized:               # scales move with their pages
-            (self.k, self.v, self.k_scale, self.v_scale) = self._copy_quant(
-                self.k, self.v, self.k_scale, self.v_scale, dst, src)
-        else:
-            self.k, self.v = self._copy(self.k, self.v, dst, src)
+        with jax.profiler.TraceAnnotation("engine.cow_flush"):
+            dst = jnp.asarray([p[0] for p in self._pending_cow], jnp.int32)
+            src = jnp.asarray([p[1] for p in self._pending_cow], jnp.int32)
+            if self.quantized:           # scales move with their pages
+                (self.k, self.v, self.k_scale, self.v_scale) = \
+                    self._copy_quant(self.k, self.v, self.k_scale,
+                                     self.v_scale, dst, src)
+            else:
+                self.k, self.v = self._copy(self.k, self.v, dst, src)
         n = len(self._pending_cow)
         self._pending_cow.clear()
         self.cow_dispatches += 1

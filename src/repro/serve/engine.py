@@ -48,8 +48,20 @@ Two engines, one compiled-cell discipline (no recompiles, ever):
     uploaded as B ints, prompt chunks uploaded as ONE ragged (B, T) token
     block (the per-step (chunk, B) forced-token/mask uploads are retired
     for prompt traffic — ``forced_upload_bytes`` stays 0 and verify.sh
-    gates it), and per-tick host-cost traces (host ms, dispatches, upload
-    bytes) feeding BENCH_serve.json's tick_overhead section.
+    gates it), a per-tick host-cost trace (host ms) and cumulative
+    counters (dispatches, upload bytes: ``PagedEngine.counters``) feeding
+    BENCH_serve.json's tick_overhead section.
+
+    The tick is instrumented where the work happens: ``step`` opens JAX
+    profiler spans (``engine.step`` and one per phase: ``engine.admit``,
+    ``engine.plan``, ``engine.cow_flush``, ``engine.sync_dirty``,
+    ``engine.dispatch_prefill``, ``engine.dispatch_decode``,
+    ``engine.readback``, ``engine.bookkeep``, ``engine.preempt``,
+    ``engine.snapshot``) on the host timeline the device planes share,
+    and builds their attributes (request ids, the tick's dispatches and
+    upload bytes) only while a trace is being recorded.  Each request is
+    stamped when it is first admitted and when the tick that sampled its
+    first output ends (``request_times``).
 
 CPU-runnable end-to-end (examples/serve_demo.py); the same step functions are
 what launch/serve.py lowers for the production mesh.
@@ -66,6 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.model import Model, sample_token
+from repro.serve.cache import COUNTERS as CACHE_COUNTERS
 from repro.serve.cache import PagedKVCache, digest_step
 from repro.serve.faults import EngineKilled
 from repro.serve.scheduler import TickScheduler
@@ -109,13 +122,13 @@ class ServeConfig:
                                       # "popularity" (fewest adoptions first)
     fairness: str = "least-served"    # page-grant order ("slot-order": legacy)
     tick_budget: int = 0              # max fresh tokens per tick (0: uncapped)
-    trace_pool: bool = True           # record per-tick util/occupancy traces
-                                      # (host-side pool walks; benchmarks
-                                      # measuring the thin tick disable it)
-    trace_ticks: bool = True          # record per-tick host-ms/dispatch/
-                                      # upload traces (cheap scalars, but
-                                      # unbounded — a long-lived server
-                                      # disables them; counters stay on)
+    trace_pool: bool = True           # record the per-tick util trace
+                                      # (benchmarks measuring the thin
+                                      # tick disable it)
+    trace_ticks: bool = True          # record the per-tick host-ms trace
+                                      # (a cheap scalar, but unbounded — a
+                                      # long-lived server disables it;
+                                      # counters stay on)
     # --- overload safety (preemption / admission / fault tolerance) ---------
     preempt: bool = True              # preempt-and-recompute when no slot
                                       # can get step capacity; False keeps
@@ -177,6 +190,27 @@ TERMINAL_STATUSES = frozenset({
     RequestStatus.REJECTED, RequestStatus.CANCELLED,
     RequestStatus.DEADLINE_EXCEEDED})
 
+# PagedEngine's cumulative counters (``PagedEngine.counters`` snapshots
+# them with the page cache's; serve/snapshot.py carries them over a restore)
+COUNTERS = (
+    "steps_run", "ticks", "no_progress_ticks", "snapshots_written",
+    "preemptions", "recompute_tokens", "rejected", "cancelled",
+    "deadline_exceeded", "quarantines", "dropped_grants", "tokens_out",
+    "tokens_appended", "shared_tokens", "joins", "stalls", "dispatches",
+    "spec_proposed", "spec_accepted", "spec_trunc_tokens",
+    "draft_dispatches", "verify_dispatches", "table_upload_bytes",
+    "forced_upload_bytes", "prefill_upload_bytes", "upload_bytes")
+
+# host spans on the JAX profiler's timeline; with no trace recording, a
+# span costs one native call and its attributes are never built
+_span = jax.profiler.TraceAnnotation
+_recording = jax.profiler.TraceAnnotation.is_enabled
+
+
+def _ids(rids) -> str:
+    """Request ids as one span attribute (a TraceMe value holds no comma)."""
+    return " ".join(str(r) for r in rids)
+
 
 @dataclasses.dataclass
 class Request:
@@ -191,6 +225,9 @@ class Request:
     deadline_tick: int = -1           # absolute engine tick; -1 = none
     emitted: List[int] = dataclasses.field(default_factory=list)
     preempts: int = 0                 # times this request lost its slot
+    # host clock (time.perf_counter), kept across preemption and requeue
+    admitted_t: Optional[float] = None     # its first admission
+    first_token_t: Optional[float] = None  # end of its first output's tick
 
 
 class ServingEngine:
@@ -592,12 +629,14 @@ class PagedEngine:
         # the forced-token-free twin: pure-decode ticks (no prompt in
         # flight) skip building and uploading the (chunk, B) forced
         # arrays entirely — a second compiled cell, compiled once
-        self._many_plain = jax.jit(
-            lambda params, tok, cache, key, steps, *, num_steps,
-            temperature: model.decode_many_paged(
+        def decode_many_paged(params, tok, cache, key, steps, *, num_steps,
+                              temperature):
+            return model.decode_many_paged(
                 params, tok, cache, key, steps, None, None,
-                num_steps=num_steps, temperature=temperature),
-            static_argnames=("num_steps", "temperature"),
+                num_steps=num_steps, temperature=temperature)
+
+        self._many_plain = jax.jit(
+            decode_many_paged, static_argnames=("num_steps", "temperature"),
             donate_argnums=(2, 3))
         # the ragged multi-token PREFILL LANE: one compiled step appends
         # and attends a (B, T) chunk of prompt tokens — a prompt costs
@@ -705,6 +744,7 @@ class PagedEngine:
         self.snapshots_written = 0        # crash-consistency snapshots
         self._last_snapshot_tick = -1     # dedupe guard for the hook
         self._reqs: Dict[int, Request] = {}
+        self._unstamped: set = set()      # admitted, no first output yet
         self.status: Dict[int, RequestStatus] = {}
         self.reject_reason: Dict[int, str] = {}
         self.preemptions = 0              # capacity preemptions + forced
@@ -729,13 +769,11 @@ class PagedEngine:
         self.spec_trunc_tokens = 0        # target K/V rows rolled back
         self.draft_dispatches = 0         # draft catch-up + propose calls
         self.verify_dispatches = 0        # target verify calls
-        self.draft_dispatch_trace: List[int] = []   # per busy tick
-        self.verify_dispatch_trace: List[int] = []
         self.shared_tokens = 0            # prompt tokens served by reference
         self.joins = 0
         self.stalls = 0
+        self.dispatches = 0               # device calls of working ticks
         self.util_trace: List[float] = []        # per-tick page utilization
-        self.occupancy_trace: List[float] = []   # per-tick row occupancy
         # --- tick-overhead accounting (the host side the roofline can't
         # see: BENCH_serve.json's tick_overhead section reads these) ------
         self.table_upload_bytes = 0       # dirty-row table/length patches
@@ -746,8 +784,6 @@ class PagedEngine:
         self.prefill_upload_bytes = 0     # (B, T) chunk tokens + grants
         self.upload_bytes = 0             # all per-tick host->device bytes
         self.host_ms_trace: List[float] = []     # host work per tick (ms)
-        self.dispatch_trace: List[int] = []      # device calls per tick
-        self.upload_trace: List[int] = []        # bytes uploaded per tick
 
     # -- request lifecycle -----------------------------------------------------
 
@@ -823,6 +859,55 @@ class PagedEngine:
     @property
     def busy(self) -> bool:
         return bool(self.queue) or any(s.active for s in self.slots)
+
+    def request_times(self, rid: int
+                      ) -> Tuple[Optional[float], Optional[float]]:
+        """(admitted_t, first_token_t) of a request on the
+        ``time.perf_counter`` clock: its first admission to a slot, and
+        the end of the tick that sampled its first output; None where it
+        has not happened (a rejected request has neither).  Preemption
+        and quarantine requeues keep both."""
+        req = self._reqs.get(rid)
+        if req is None:
+            return None, None
+        return req.admitted_t, req.first_token_t
+
+    def cell_hlo(self) -> Dict[str, str]:
+        """The compiled HLO text of the cells a plain tick runs (the
+        prefill lane and the decode cell), keyed by XLA module name.  A
+        device trace names each op by its instruction; this text gives
+        the instruction's ``op_name``, whose named scope says which part
+        of the model step the op is (bench/spans.py).  Lowered at the
+        engine's own shapes, so the compiles are the ones the ticks ran
+        (cache hits where a persistent compile cache is on)."""
+        B = self.cfg.max_batch
+        cache = {"k": self.kv.k, "v": self.kv.v,
+                 "table": self._table_dev, "length": self._length_dev}
+        if self.kv.quantized:
+            cache["k_scale"] = self.kv.k_scale
+            cache["v_scale"] = self.kv.v_scale
+        grants = jnp.zeros((B,), jnp.int32)
+        temp = self.cfg.temperature
+        cells = [self._many_plain.lower(
+            self.params, jnp.zeros((B, 1), jnp.int32), cache, self.key,
+            grants, num_steps=self._chunk, temperature=temp)]
+        if self._chunk_tokens:
+            cells.append(self._prefill_lane.lower(
+                self.params, jnp.zeros((B, self._chunk_tokens), jnp.int32),
+                cache, self.key, grants, temperature=temp))
+        out = {}
+        for lowered in cells:
+            text = lowered.compile().as_text()
+            out[text.split(",", 1)[0].split()[1]] = text
+        return out
+
+    def counters(self) -> Dict[str, int]:
+        """A snapshot of every cumulative counter, the engine's and its
+        page cache's: the difference of two snapshots is what the ticks
+        between them did."""
+        out = {c: int(getattr(self, c)) for c in COUNTERS}
+        out.update((c, int(getattr(self.kv, c))) for c in CACHE_COUNTERS)
+        return out
 
     def run(self) -> Dict[int, List[int]]:
         """Drain queue + slots; returns {rid: generated tokens}."""
@@ -903,11 +988,15 @@ class PagedEngine:
         bit-identical to stepwise decode)."""
         slot = self.slots[i]
         req = self._reqs[slot.rid]
-        req.emitted.extend(slot.out)
-        req.preempts += 1
-        self.status[req.rid] = RequestStatus.QUEUED
-        self.queue.insert(0, req)
-        self._release_slot(i)
+        with _span("engine.preempt") as span:
+            if _recording():
+                span.set_metadata(rids=_ids([req.rid]),
+                                  quarantine=int(quarantine))
+            req.emitted.extend(slot.out)
+            req.preempts += 1
+            self.status[req.rid] = RequestStatus.QUEUED
+            self.queue.insert(0, req)
+            self._release_slot(i)
         if quarantine:
             self._quarantined[i] = self.ticks \
                 + max(1, self.cfg.quarantine_ticks)
@@ -988,7 +1077,10 @@ class PagedEngine:
             return "retained", entry, n_ret
         return None, None, 0
 
-    def _admit(self) -> None:
+    def _admit(self) -> List[int]:
+        """Give free slots to the queue's head requests; returns their
+        ids."""
+        admitted: List[int] = []
         for i, slot in enumerate(self.slots):
             if slot.active or not self.queue:
                 continue
@@ -1027,11 +1119,16 @@ class PagedEngine:
                 self._pindex.add(i, prompt[:n_shared])
             self._feed[i] = prompt[n_shared]
             self.status[req.rid] = RequestStatus.RUNNING
+            if req.admitted_t is None:
+                req.admitted_t = time.perf_counter()
+                self._unstamped.add(req.rid)
+            admitted.append(req.rid)
             if req.preempts:
                 # re-appended work (prefix-shared tokens cost nothing) —
                 # the bench's recompute-overhead fraction reads this
                 self.recompute_tokens += len(prompt) - n_shared
             self.joins += 1
+        return admitted
 
     def _finish(self, i: int) -> None:
         slot = self.slots[i]
@@ -1286,7 +1383,20 @@ class PagedEngine:
         prefill chunk (the per-step (chunk, B) forced-token/mask uploads
         are retired for prompts — ``forced_upload_bytes`` stays 0 and is
         gated), and a pure-decode tick runs the forced-token-free twin
-        cell."""
+        cell.
+
+        The tick runs inside the profiler span ``engine.step`` and each
+        phase inside its own (module docstring); the step span carries
+        the tick's dispatches and upload bytes while a trace records."""
+        with _span("engine.step") as span:
+            recording = _recording()
+            work = self._tick(recording)
+            if recording and work is not None:
+                span.set_metadata(**work)
+
+    def _tick(self, recording: bool) -> Optional[Dict[str, int]]:
+        """``step``'s body; returns the working tick's device calls and
+        upload bytes (None for a tick with no work)."""
         cfg = self.cfg
         chunk = self._chunk
         T = self._chunk_tokens
@@ -1294,10 +1404,14 @@ class PagedEngine:
         self.ticks += 1
         self._apply_faults()
         self._expire_deadlines()
-        self._admit()
+        with _span("engine.admit") as span:
+            admitted = self._admit()
+            if recording and admitted:
+                span.set_metadata(rids=_ids(admitted))
         cow_disp0 = self.kv.cow_dispatches
-        plan = self.scheduler.plan(self.slots, self.kv, chunk,
-                                   prefill_tokens=T)
+        with _span("engine.plan"):
+            plan = self.scheduler.plan(self.slots, self.kv, chunk,
+                                       prefill_tokens=T)
         self.stalls += plan.stalled
         # PREEMPT-AND-RECOMPUTE: when no slot can get step capacity, evict
         # victims (fewest tokens generated, then most pages held) until the
@@ -1306,8 +1420,9 @@ class PagedEngine:
         # iteration drops one active slot, so the loop is bounded by B.
         while not plan.any_work and cfg.preempt \
                 and self._preempt_for_capacity():
-            plan = self.scheduler.plan(self.slots, self.kv, chunk,
-                                       prefill_tokens=T)
+            with _span("engine.plan"):
+                plan = self.scheduler.plan(self.slots, self.kv, chunk,
+                                           prefill_tokens=T)
             self.stalls += plan.stalled
         # dropped-grant fault: the victims' granted work vanishes AFTER
         # planning (a dropped grant must look like lost work, not trigger
@@ -1345,7 +1460,7 @@ class PagedEngine:
                         f"{len(self.kv.free)}, seized="
                         f"{len(self.kv.seized)})")
             self._maybe_snapshot()
-            return
+            return None
         self._idle = 0
         B = len(self.slots)
         steps = plan.steps
@@ -1356,8 +1471,9 @@ class PagedEngine:
         # dirty-row sync of the device table/length mirrors: only rows
         # admission/COW/eviction/defrag/truncation touched; nothing in
         # steady state.
-        self._table_dev, self._length_dev, row_bytes = self._sync_dirty(
-            self.kv, self._table_dev, self._length_dev)
+        with _span("engine.sync_dirty"):
+            self._table_dev, self._length_dev, row_bytes = self._sync_dirty(
+                self.kv, self._table_dev, self._length_dev)
         if row_bytes:
             self.table_upload_bytes += row_bytes
             tick_upload += row_bytes
@@ -1372,20 +1488,21 @@ class PagedEngine:
         # --- prefill lane: one ragged (B, T) chunk of prompt tokens ------
         nxt = None
         if pgr.any():
-            tok_mat = np.full((B, T), cfg.pad_id, np.int32)
-            for i, slot in enumerate(self.slots):
-                g = int(pgr[i])
-                if g:
-                    tok_mat[i, 0] = self._feed[i]
-                    if g > 1:
-                        tok_mat[i, 1:g] = slot.forced[:g - 1]
-            pbytes = B * (T + 1) * 4          # token block + grant vector
-            self.prefill_upload_bytes += pbytes
-            tick_upload += pbytes
-            nxt, cache, self.key = self._prefill_lane(
-                self.params, jnp.asarray(tok_mat), cache, self.key,
-                jnp.asarray(pgr), temperature=cfg.temperature)
-            dispatches += 1
+            with _span("engine.dispatch_prefill"):
+                tok_mat = np.full((B, T), cfg.pad_id, np.int32)
+                for i, slot in enumerate(self.slots):
+                    g = int(pgr[i])
+                    if g:
+                        tok_mat[i, 0] = self._feed[i]
+                        if g > 1:
+                            tok_mat[i, 1:g] = slot.forced[:g - 1]
+                pbytes = B * (T + 1) * 4          # token block + grant vector
+                self.prefill_upload_bytes += pbytes
+                tick_upload += pbytes
+                nxt, cache, self.key = self._prefill_lane(
+                    self.params, jnp.asarray(tok_mat), cache, self.key,
+                    jnp.asarray(pgr), temperature=cfg.temperature)
+                dispatches += 1
 
         # --- decode lane: the fused scan over decode grants, or (spec
         # mode) the draft-propose + target-verify pipeline ----------------
@@ -1395,41 +1512,42 @@ class PagedEngine:
         d_disp = v_disp = 0
         spec_wait = 0.0
         if steps.any():
-            if self._spec:
-                (greedy, accept, vgr, cache, d_up, d_disp, v_disp,
-                 spec_wait) = self._spec_decode(steps, chunk, cache)
-                tick_upload += d_up
-                dispatches += d_disp + v_disp
-                self.draft_dispatches += d_disp
-                self.verify_dispatches += v_disp
-            else:
-                tick_upload += 2 * B * 4      # feed tokens + step grants
-                feed = jnp.asarray(self._feed)[:, None]
-                steps_dev = jnp.asarray(steps)
-                prompt_in_flight = any(s.active and s.forced and steps[i]
-                                       for i, s in enumerate(self.slots))
-                if prompt_in_flight:
-                    # legacy prefill-by-decode (lane disabled): prompts
-                    # ride the decode cell as forced tokens
-                    forced_tok = np.full((chunk, B), cfg.pad_id, np.int32)
-                    forced_mask = np.zeros((chunk, B), bool)
-                    for i, slot in enumerate(self.slots):
-                        for s in range(min(len(slot.forced),
-                                           int(steps[i]))):
-                            forced_tok[s, i] = slot.forced[s]
-                            forced_mask[s, i] = True
-                    forced_bytes = chunk * B * (4 + 1)
-                    self.forced_upload_bytes += forced_bytes
-                    tick_upload += forced_bytes
-                    toks, cache, self.key = self._many(
-                        self.params, feed, cache, self.key, steps_dev,
-                        jnp.asarray(forced_tok), jnp.asarray(forced_mask),
-                        num_steps=chunk, temperature=cfg.temperature)
+            with _span("engine.dispatch_decode"):
+                if self._spec:
+                    (greedy, accept, vgr, cache, d_up, d_disp, v_disp,
+                     spec_wait) = self._spec_decode(steps, chunk, cache)
+                    tick_upload += d_up
+                    dispatches += d_disp + v_disp
+                    self.draft_dispatches += d_disp
+                    self.verify_dispatches += v_disp
                 else:
-                    toks, cache, self.key = self._many_plain(
-                        self.params, feed, cache, self.key, steps_dev,
-                        num_steps=chunk, temperature=cfg.temperature)
-                dispatches += 1
+                    tick_upload += 2 * B * 4      # feed tokens + step grants
+                    feed = jnp.asarray(self._feed)[:, None]
+                    steps_dev = jnp.asarray(steps)
+                    prompt_in_flight = any(s.active and s.forced and steps[i]
+                                           for i, s in enumerate(self.slots))
+                    if prompt_in_flight:
+                        # legacy prefill-by-decode (lane disabled): prompts
+                        # ride the decode cell as forced tokens
+                        forced_tok = np.full((chunk, B), cfg.pad_id, np.int32)
+                        forced_mask = np.zeros((chunk, B), bool)
+                        for i, slot in enumerate(self.slots):
+                            for s in range(min(len(slot.forced),
+                                               int(steps[i]))):
+                                forced_tok[s, i] = slot.forced[s]
+                                forced_mask[s, i] = True
+                        forced_bytes = chunk * B * (4 + 1)
+                        self.forced_upload_bytes += forced_bytes
+                        tick_upload += forced_bytes
+                        toks, cache, self.key = self._many(
+                            self.params, feed, cache, self.key, steps_dev,
+                            jnp.asarray(forced_tok), jnp.asarray(forced_mask),
+                            num_steps=chunk, temperature=cfg.temperature)
+                    else:
+                        toks, cache, self.key = self._many_plain(
+                            self.params, feed, cache, self.key, steps_dev,
+                            num_steps=chunk, temperature=cfg.temperature)
+                    dispatches += 1
         self.kv.k = cache["k"]
         self.kv.v = cache["v"]
         if self.kv.quantized:
@@ -1442,14 +1560,62 @@ class PagedEngine:
         self.steps_run += 1
         if cfg.trace_pool:
             self.util_trace.append(self.kv.utilization())
-            self.occupancy_trace.append(self.kv.occupancy())
 
         t1 = time.perf_counter()
-        toks_np = np.array(toks) if toks is not None else None  # device wait
-        nxt_np = np.array(nxt) if nxt is not None else None
-        greedy_np = np.array(greedy) if greedy is not None else None
-        accept_np = np.array(accept) if accept is not None else None
+        with _span("engine.readback"):                  # device wait
+            toks_np = np.array(toks) if toks is not None else None
+            nxt_np = np.array(nxt) if nxt is not None else None
+            greedy_np = np.array(greedy) if greedy is not None else None
+            accept_np = np.array(accept) if accept is not None else None
         t2 = time.perf_counter()
+        with _span("engine.bookkeep") as span:
+            live = [s.rid for s in self.slots if s.active] if recording \
+                else ()
+            self._bookkeep(pgr, steps, vgr, toks_np, nxt_np, greedy_np,
+                           accept_np)
+            if recording:
+                done = [r for r in live
+                        if self.status[r] in TERMINAL_STATUSES]
+                if done:
+                    span.set_metadata(rids=_ids(done))
+        t3 = time.perf_counter()
+        if cfg.trace_ticks:
+            # host cost of the tick = everything but the device waits
+            # (the mid-tick proposal sync in spec mode is a device wait)
+            self.host_ms_trace.append(
+                ((t1 - t0 - spec_wait) + (t3 - t2)) * 1e3)
+        self.dispatches += dispatches
+        self.upload_bytes += tick_upload
+        self._maybe_snapshot()
+        t_end = time.perf_counter()
+        for rid in tuple(self._unstamped):
+            if self._output_len(rid) > 0:
+                self._reqs[rid].first_token_t = t_end
+                self._unstamped.discard(rid)
+            elif self.status[rid] in TERMINAL_STATUSES:
+                self._unstamped.discard(rid)
+        if not recording:
+            return None
+        work = {"dispatches": dispatches, "upload_bytes": tick_upload}
+        if self._spec:
+            work.update(draft_dispatches=d_disp, verify_dispatches=v_disp)
+        return work
+
+    def _output_len(self, rid: int) -> int:
+        """Output tokens a request holds (kept across preemption)."""
+        if rid in self.results:
+            return len(self.results[rid])
+        held = sum(len(s.out) for s in self.slots
+                   if s.active and s.rid == rid)
+        return len(self._reqs[rid].emitted) + held
+
+    def _bookkeep(self, pgr, steps, vgr, toks_np, nxt_np, greedy_np,
+                  accept_np) -> None:
+        """A working tick's host bookkeeping over the tokens read back:
+        the poison fault and output guard, then the prefill lane's and
+        the decode lane's emits, finishes and feeds, then quarantine."""
+        cfg = self.cfg
+        B = len(self.slots)
         # poison fault: nonfinite logits make the sampler return garbage —
         # modeled as an out-of-vocab sentinel overwriting the slot's
         # sampled tokens for this tick (in spec mode the WHOLE verified
@@ -1560,19 +1726,6 @@ class PagedEngine:
         for i in sorted(poisoned):
             if self.slots[i].active:
                 self._preempt(i, quarantine=True)
-        t3 = time.perf_counter()
-        if cfg.trace_ticks:
-            # host cost of the tick = everything but the device waits
-            # (the mid-tick proposal sync in spec mode is a device wait)
-            self.host_ms_trace.append(
-                ((t1 - t0 - spec_wait) + (t3 - t2)) * 1e3)
-            self.dispatch_trace.append(dispatches)
-            self.upload_trace.append(tick_upload)
-            if self._spec:
-                self.draft_dispatch_trace.append(d_disp)
-                self.verify_dispatch_trace.append(v_disp)
-        self.upload_bytes += tick_upload
-        self._maybe_snapshot()
 
     # -- crash consistency (serve/snapshot.py) -----------------------------------
 
@@ -1588,9 +1741,10 @@ class PagedEngine:
                 or self.ticks == self._last_snapshot_tick:
             return
         from repro.serve import snapshot as _snap
-        _snap.save_snapshot(
-            self, _snap.snapshot_path(cfg.snapshot_dir, self.ticks))
-        _snap.prune_snapshots(cfg.snapshot_dir, cfg.snapshot_keep)
+        with _span("engine.snapshot"):
+            _snap.save_snapshot(
+                self, _snap.snapshot_path(cfg.snapshot_dir, self.ticks))
+            _snap.prune_snapshots(cfg.snapshot_dir, cfg.snapshot_keep)
         self.snapshots_written += 1
         self._last_snapshot_tick = self.ticks
 

@@ -69,8 +69,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve.cache import COUNTERS as CACHE_COUNTERS
 from repro.serve.cache import (PagedKVCache, RetainedPrefix,
                                prefix_digests)
+from repro.serve.engine import COUNTERS as ENGINE_COUNTERS
 
 MAGIC = b"RPSNAP01"
 # 2: head-major pools (L, num_pages, KV, page, hd) and scales
@@ -78,23 +80,11 @@ MAGIC = b"RPSNAP01"
 # and is refused with SnapshotMismatchError, never misread
 VERSION = 2
 
-# PagedKVCache counters that ride along so a restored engine's stats and
-# bench sections stay continuous across the restart (none affect output)
-_CACHE_COUNTERS = (
-    "cow_copies", "cow_bytes", "cow_dispatches", "shared_pages",
-    "retained_hits", "retained_hit_tokens", "retained_reclaimed_pages",
-    "retained_dropped")
-
-# engine scalar counters restored verbatim (same continuity argument)
-_ENGINE_COUNTERS = (
-    "steps_run", "ticks", "_idle", "no_progress_ticks", "_next_rid",
-    "preemptions", "recompute_tokens", "rejected", "cancelled",
-    "deadline_exceeded", "quarantines", "dropped_grants", "tokens_out",
-    "tokens_appended", "spec_proposed", "spec_accepted",
-    "spec_trunc_tokens", "draft_dispatches", "verify_dispatches",
-    "shared_tokens", "joins", "stalls", "table_upload_bytes",
-    "forced_upload_bytes", "prefill_upload_bytes", "upload_bytes",
-    "snapshots_written")
+# engine scalars restored verbatim: its counters and the page cache's
+# (CACHE_COUNTERS) ride along so a restored engine's stats and bench
+# sections stay continuous across the restart (none affect output), and
+# the idle streak and next request id
+_ENGINE_COUNTERS = ("_idle", "_next_rid") + ENGINE_COUNTERS
 
 
 class SnapshotError(RuntimeError):
@@ -218,7 +208,7 @@ def _cache_state(kv: PagedKVCache, tag: str,
                       "pages": [int(p) for p in e.pages],
                       "stamp": int(e.stamp), "hits": int(e.hits)}
                      for e in kv.retained],
-        "counters": {c: int(getattr(kv, c)) for c in _CACHE_COUNTERS},
+        "counters": {c: int(getattr(kv, c)) for c in CACHE_COUNTERS},
     }
 
 
@@ -255,7 +245,7 @@ def _restore_cache(kv: PagedKVCache, tag: str, state: Dict[str, Any],
         kv.retained.append(entry)
         for key in entry.keys:
             kv._retained_keys.setdefault(key, []).append(entry)
-    for c in _CACHE_COUNTERS:
+    for c in CACHE_COUNTERS:
         setattr(kv, c, int(state["counters"][c]))
     # every device mirror row rebuilds through the existing dirty-row
     # patcher on the next tick — restore never grows a second upload path
@@ -342,8 +332,10 @@ def _restore_engine_state(engine, state: Dict[str, Any],
                            for k, v in state["fault_counts"].items()}
     engine._drop_slots = set()
     engine._poison_slots = set()
+    # a counter newer than the file's writer reads as 0: version-2 files
+    # written before the engine counted ``dispatches`` lack it
     for c in _ENGINE_COUNTERS:
-        setattr(engine, c, int(state["counters"][c]))
+        setattr(engine, c, int(state["counters"].get(c, 0)))
     # the live prefix index is DERIVED state: rebuild it from the
     # restored histories exactly as the ticks that built it would have
     engine._pindex.__init__()

@@ -659,12 +659,15 @@ def test_steady_state_tick_uploads_zero_table_bytes(small_model):
     pe.step()                     # settle residual dirty rows
     tb0, fb0 = pe.table_upload_bytes, pe.forced_upload_bytes
     d0 = pe.kv.cow_dispatches
+    c0 = pe.counters()
     pe.step()                     # a pure steady-state decode tick
+    c1 = pe.counters()
     assert pe.table_upload_bytes == tb0, "steady tick re-uploaded the table"
     assert pe.forced_upload_bytes == fb0, "steady tick built forced arrays"
     assert pe.kv.cow_dispatches == d0
-    assert pe.dispatch_trace[-1] == 1        # just the fused decode cell
-    assert pe.upload_trace[-1] == 2 * pe.cfg.max_batch * 4  # feed + grants
+    assert c1["dispatches"] - c0["dispatches"] == 1   # the fused decode cell
+    assert c1["upload_bytes"] - c0["upload_bytes"] == \
+        2 * pe.cfg.max_batch * 4                      # feed + grants
 
 
 # ---------------------------------------------------------------------------

@@ -387,6 +387,44 @@ def test_token_major_version_refused(harness, tmp_path):
         snap.restore_engine(_mk(model, params, None), path)
 
 
+def test_counter_missing_from_an_older_file_reads_zero(harness, tmp_path):
+    """A version-2 file written before the engine counted ``dispatches``
+    restores with that counter at 0 and the rest verbatim, and the
+    restored engine drains bit-identically to the one that wrote it."""
+    import zlib
+    model, params = harness
+    items = _make_items(model, seed=7, n=4, last_tick=1)
+    pe = _mk(model, params, None)
+    for _, p, b in items:
+        pe.submit(p, b)
+    for _ in range(3):
+        pe.step()
+    assert pe.dispatches > 0
+    path = snap.save_snapshot(pe, snap.snapshot_path(str(tmp_path), 3))
+    blob = open(path, "rb").read()
+    off = len(snap.MAGIC)
+    hlen = int.from_bytes(blob[off:off + 8], "little")
+    header = json.loads(blob[off + 8:off + 8 + hlen])
+    body = blob[off + 8 + hlen:]
+    state = json.loads(body[:header["state_len"]])
+    del state["engine"]["counters"]["dispatches"]
+    state_b = json.dumps(state).encode("utf-8")
+    body = state_b + body[header["state_len"]:]
+    header.update(state_len=len(state_b), body_len=len(body),
+                  body_crc32=zlib.crc32(body) & 0xFFFFFFFF)
+    header_b = json.dumps(header).encode("utf-8")
+    with open(path, "wb") as f:
+        f.write(snap.MAGIC + len(header_b).to_bytes(8, "little") + header_b
+                + body)
+    fresh = snap.restore_engine(_mk(model, params, None), path)
+    assert fresh.dispatches == 0
+    assert fresh.steps_run == pe.steps_run
+    assert fresh.tokens_appended == pe.tokens_appended
+    _run_schedule(pe, items)
+    _run_schedule(fresh, items)
+    _assert_runs_identical(fresh, pe, "old file")
+
+
 # ---------------------------------------------------------------------------
 # wedge detector: configurable threshold, surfaced counter
 # ---------------------------------------------------------------------------

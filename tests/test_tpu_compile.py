@@ -97,3 +97,70 @@ def test_paged_prefill_compiles_for_v5e(one_chip, kv_dtype, pps):
         _ints((SLOTS,), one_chip), _ints((SLOTS,), one_chip),
         _ints((), one_chip), scale, scale).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("lane", ["prefill", "decode"])
+def test_engine_lanes_name_their_device_work_for_v5e(one_chip, monkeypatch,
+                                                     lane):
+    """The serving engine's two compiled cells at toy widths, compiled
+    for the described v5e as its profiler sees them: the lane's module
+    keeps its jit name, its Mosaic kernel is the custom call named after
+    the Pallas kernel, and every matmul and every custom call the program
+    put there carries exactly one of the model step's named scopes (the
+    custom calls XLA adds, such as buffer allocations, carry no
+    ``op_name`` at all)."""
+    import dataclasses
+    import re
+
+    import numpy as np
+
+    from repro.kernels.decode_attention import ops
+    from repro.models import get_model
+    from repro.serve.engine import PagedEngine, ServeConfig
+
+    scopes = ("embed", "qkv", "kv_write", "attn", "out_proj", "ffn", "head",
+              "sample")
+    kernel = {"prefill": "ragged_prefill_attention",
+              "decode": "paged_decode_attention"}[lane]
+    cfg = dataclasses.replace(CFG, attention_impl="pallas", n_layers=2,
+                              d_model=256, d_ff=512, vocab_size=512)
+    model = get_model(cfg)
+    eng = PagedEngine(model, model.init(jax.random.key(0)), ServeConfig(
+        max_batch=SLOTS, max_seq=MAX_BLOCKS * PAGE, page_size=PAGE,
+        num_pages=NUM_PAGES, prefill_chunk=4, prefill_chunk_tokens=64,
+        trace_pool=False))
+    monkeypatch.setattr(ops, "auto_interpret", lambda interpret=None: False)
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(shape, eng.params)
+    cache = jax.tree.map(shape, {"k": eng.kv.k, "v": eng.kv.v,
+                                 "table": eng._table_dev,
+                                 "length": eng._length_dev})
+    key, grants = shape(eng.key), shape(np.zeros((SLOTS,), np.int32))
+    if lane == "prefill":
+        lowered = eng._prefill_lane.lower(
+            params, shape(np.zeros((SLOTS, 64), np.int32)), cache, key,
+            grants, temperature=0.0)
+    else:
+        lowered = eng._many_plain.lower(
+            params, shape(np.zeros((SLOTS, 1), np.int32)), cache, key,
+            grants, num_steps=4, temperature=0.0)
+    text = lowered.compile().as_text()
+    assert re.search(r"HloModule (\S+?),", text).group(1) == \
+        f"jit_{lane}_many_paged"
+    kernels = 0
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = .*? (custom-call|convolution|"
+                     r"dot)\(", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if not m or (m.group(2) == "custom-call" and not op):
+            continue
+        path = op.group(1).split("/") if op else []
+        assert len([c for c in path if c in scopes]) == 1, line[:200]
+        if "tpu_custom_call" in line:
+            assert m.group(1).split(".")[0] == kernel
+            assert "attn" in path
+            kernels += 1
+    assert kernels == 1                     # one kernel call in the scan body
