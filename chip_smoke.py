@@ -5,7 +5,8 @@ Serves phi4-mini-3.8b at its full published widths (32 layers, d_model
 random weights through the same engine construction as
 ``python -m repro.launch.serve`` (``build_engine``), with the Pallas paged
 kernels on the served path (``attention_impl="pallas"``).  Then it checks
-each paged kernel on the chip against its jnp oracle at the served shapes.
+each paged kernel on the chip against its jnp oracle at the served shapes,
+and the decode kernel also at two narrower head widths.
 
     python3 chip_smoke.py
 
@@ -36,6 +37,9 @@ NEW_TOKENS = 32
 PREFILL_CHUNK = 256         # prompt tokens per prefill-lane step
 MAX_SEQ = 640               # longest prompt + NEW_TOKENS, page-rounded
 KERNEL_CHUNKS = (64, 256)   # ragged prefill chunks checked on the chip
+# decode also checked at these configs' head widths (2 of their layers):
+# head dims of 64 and 112, which fill part of a 128-lane row
+NARROW_ARCHS = ("qwen2-0.5b", "zamba2-7b")
 # bf16 outputs of two f32-accumulating softmax paths: a few bf16 ulps at
 # unit magnitude (one ulp at 1.0 is 2**-7)
 KERNEL_TOL = 2e-2
@@ -128,31 +132,37 @@ def _paged_inputs(key, cfg, *, slots, page, max_blocks, T, kv_dtype):
             table.reshape(slots, max_blocks), layer, k_scale, v_scale)
 
 
-def check_kernels(cfg, *, slots, page, max_blocks, chunks):
+def check_kernels(cfg, *, slots, page, max_blocks, chunks,
+                  narrow=NARROW_ARCHS):
     """Each paged Pallas kernel against its jnp gather oracle at the
-    served shapes: decode over bf16 and int8 pools at 1 and 4 pages per
-    grid step, and ragged prefill at each chunk in ``chunks``.  Returns
+    served shapes: decode over bf16 and int8 pools at the page block the
+    kernel derives from the pool and at 4 pages a block, decode at the
+    head widths of each config in ``narrow`` (2 layers, derived block),
+    and ragged prefill at each chunk in ``chunks``.  Returns
     [(name, max_abs_err, max_abs_ref)]; raises past ``KERNEL_TOL``."""
+    from repro import configs
     from repro.kernels.decode_attention import ops, ref
     span = max_blocks * page
     rng = np.random.RandomState(SEED)
     key = jax.random.key(SEED)
     cases = []
-    for kv_dtype in ("bf16", "int8"):
-        for pps in (1, 4):
-            key, sub = jax.random.split(key)
-            q, kp, vp, tbl, layer, ks, vs = _paged_inputs(
-                sub, cfg, slots=slots, page=page, max_blocks=max_blocks,
-                T=1, kv_dtype=kv_dtype)
-            lens = jnp.asarray(rng.randint(1, span + 1, size=slots),
-                               jnp.int32)
-            kernel = jax.jit(functools.partial(ops.paged_decode_attention,
-                                               pages_per_step=pps))
-            got = kernel(q, kp, vp, tbl, lens, layer, k_scale=ks, v_scale=vs)
-            want = jax.jit(ref.paged_decode_attention_ref)(
-                q, kp, vp, tbl, lens, layer, ks, vs)
-            cases.append((f"paged decode {kv_dtype} pages_per_step={pps}",
-                          got, want))
+    decodes = [(cfg, kv_dtype, pps, f"pages_per_step={pps or 'derived'}")
+               for kv_dtype in ("bf16", "int8") for pps in (None, 4)]
+    decodes += [(dataclasses.replace(configs.get(arch), n_layers=2),
+                 kv_dtype, None, f"{arch} widths")
+                for arch in narrow for kv_dtype in ("bf16", "int8")]
+    for dcfg, kv_dtype, pps, label in decodes:
+        key, sub = jax.random.split(key)
+        q, kp, vp, tbl, layer, ks, vs = _paged_inputs(
+            sub, dcfg, slots=slots, page=page, max_blocks=max_blocks,
+            T=1, kv_dtype=kv_dtype)
+        lens = jnp.asarray(rng.randint(1, span + 1, size=slots), jnp.int32)
+        kernel = jax.jit(functools.partial(ops.paged_decode_attention,
+                                           pages_per_step=pps))
+        got = kernel(q, kp, vp, tbl, lens, layer, k_scale=ks, v_scale=vs)
+        want = jax.jit(ref.paged_decode_attention_ref)(
+            q, kp, vp, tbl, lens, layer, ks, vs)
+        cases.append((f"paged decode {kv_dtype} {label}", got, want))
     for T in chunks:
         key, sub = jax.random.split(key)
         q, kp, vp, tbl, layer, _, _ = _paged_inputs(

@@ -56,9 +56,11 @@ class ArchConfig:
     tie_embeddings: bool = False
     # --- implementation switches (hillclimb levers) -----------------------------
     attention_impl: str = "reference"     # reference | pallas
-    pages_per_step: int = 1          # paged decode kernel: pages swept per
-                                     # grid step (page-list blocking; cuts
-                                     # grid steps by P for long slots)
+    pages_per_step: int = 1          # ragged paged-prefill kernel: pages
+                                     # swept per grid step (page-list
+                                     # blocking; cuts grid steps by P for
+                                     # long slots).  The decode kernel
+                                     # derives its page block from the pool
     prefill_chunk_tokens: int = 0    # ragged paged-prefill lane: prompt
                                      # tokens per chunked-prefill kernel
                                      # step (0 = auto: 2x the serving page

@@ -30,7 +30,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_table: jax.Array,
                            kv_len: jax.Array, layer=0,
-                           pages_per_step: int = 1,
+                           pages_per_step: Optional[int] = None,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
@@ -38,10 +38,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     head-major pools (4D single-layer accepted); block_table
     (B, max_blocks) int32 (page 0 = reserved null page); kv_len (B,) int32
     per-slot token counts;
-    layer — pool layer to address; pages_per_step — page-list blocking
-    factor (P pages swept per grid step); k_scale, v_scale — optional
-    (L, num_pages, KV, page, 1) f32 per-row scales for int8 pools.
-    Returns (B, 1, H, D)."""
+    layer — pool layer to address; pages_per_step — pages copied per loop
+    iteration (None = derived from the pool's shape and dtype); k_scale,
+    v_scale — optional (L, num_pages, KV, page, 1) f32 per-row scales for
+    int8 pools.  Returns (B, 1, H, D)."""
     return _paged.paged_decode_attention_fwd(
         q, k_pool, v_pool, block_table, kv_len, layer,
         k_scale=k_scale, v_scale=v_scale,

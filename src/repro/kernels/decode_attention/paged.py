@@ -2,48 +2,50 @@
 
 The paged serving hot path: one new query per slot attends over that slot's
 PAGES of a shared (num_pages, KV, page, D) pool.  The physical page holding
-each logical block comes from a scalar-prefetched block table — the DMA
-address is computed from SMEM before the tile is fetched, so the kernel
-streams ONLY the pages a slot actually owns.  That is the point for the
-instruction roofline: the decode step's transaction count is proportional to
-live tokens (max_blocks x page per slot) instead of ``max_seq``, which
-``core.hlo_counters`` verifies on the jnp gather oracle (the dense cache
-reads every row of a (B, max_seq, KV, D) cache whether or not it is live).
+each logical block comes from a scalar-prefetched block table, and the
+kernel copies ONLY the pages that hold a slot's ``kv_len`` tokens: its work
+and its HBM traffic follow live tokens, not the table's width
+(``max_blocks``) and not ``max_seq``.  ``core.hlo_counters`` verifies the
+same property on the jnp gather oracle (the dense cache reads every row of
+a (B, max_seq, KV, D) cache whether or not it is live).
 
-Shape strategy (mirrors the dense decode kernel in ``decode.py``):
+Shape strategy:
 
-  * grid = (B, KV, ceil(max_blocks / P)) with P = ``pages_per_step`` —
-    logical blocks are the MINOR axis, so the online-softmax state for one
-    (slot, kv-head) lives in VMEM scratch across the page sweep.
-  * MULTI-PAGE BLOCKING (``pages_per_step`` > 1): each grid step scalar-
-    prefetches a page LIST — P physically-scattered pages resolved through
-    the block table — and sweeps all P through the online-softmax update
-    before the next grid step.  Grid steps (and their per-step init/
-    finalize + index bookkeeping overhead) shrink by P for long slots; the
-    tiles fetched are identical, so the transaction census is unchanged.
-    The block table is padded to a multiple of P with null-page entries so
-    every prefetched address stays valid (``grid_steps``/``padded_blocks``
-    expose the blocking arithmetic for tests).
-  * GQA without materializing repeated kv heads: q reshaped to
-    (B, KV, G, D), each page runs [G, D] x [D, page] on the MXU.
-  * per-slot ``kv_len`` + the flattened block table + the layer index
-    arrive via scalar prefetch (SMEM): the k/v BlockSpec index_maps read
-    ``tbl[b * padded_blocks + j * P + p]`` to pick the p-th physical page
-    of grid step j, and pages at or beyond the slot's length are skipped
-    with ``pl.when`` (their table entries point at the reserved null page
-    0, so the prefetch address is always valid).
-  * the pool stays STACKED across layers: the layer-scan caller passes
-    its trip counter as the ``layer`` scalar and the index_map
-    addresses (layer, page, head) directly — no per-layer pool slice
-    is ever materialized (a dynamic-slice of the full pool per layer is
-    exactly the max_seq-proportional traffic the paged design removes).
-  * HEAD-MAJOR pages (L, num_pages, KV, page, D): one (slot, kv-head)
-    tile is the block (1, 1, 1, page, D), whose last two dims are the
-    whole (page, D) plane of the array — the Mosaic TPU compiler accepts
-    such a block at any page size, where a (page, 1, D) slice of a
-    token-major (page, KV, D) page is refused (a block's last two dims
-    must be (8, 128)-divisible or whole).  int8 scales ride the same
-    way as (L, num_pages, KV, page, 1).
+  * grid = (B,): one grid step per slot.  The slot's ``kv_len``, the
+    flattened block table and the layer index arrive via scalar prefetch
+    (SMEM).
+  * the pools stay in HBM (``memory_space=pl.ANY``).  Inside the step a
+    ``fori_loop`` runs ``block_trips(kv_len, P * page)`` iterations — a
+    trip count read from SMEM, so no iteration runs for a block at or past
+    ``kv_len``.  Iteration n copies the block's P live pages
+    ``pool[layer, tbl[b, n*P + p]]``, each a whole head-major (KV, page, D)
+    page, contiguous in HBM, with one async copy per page and pool.
+  * DOUBLE BUFFERING: the copies land in a (2, P, KV, page, row) VMEM
+    scratch; block n+1 is fetched while block n is computed.  Pages past
+    ``kv_len`` in the last block are not copied; their rows are masked.
+  * ALL KV HEADS in the step: q arrives as (KV, G, D) and each KV head runs
+    the online softmax over the block's P*page rows, [G, D] x [D, P*page]
+    on the MXU (GQA without materializing repeated kv heads).  bf16 tiles
+    feed the MXU as they are, accumulating in f32; softmax, probabilities
+    and the accumulator are f32.
+  * the block size P comes from the pool's shape and dtype
+    (``block_pages``): about 512 KiB of VMEM per pool and block, 16 pages
+    of 16 tokens at 8 KV heads of 128 bf16 lanes.  ``pages_per_step``
+    overrides it (tests cover small and odd P).
+  * the pool stays STACKED across layers: the layer-scan caller passes its
+    trip counter as the ``layer`` scalar and each copy addresses
+    (layer, page) directly — no per-layer pool slice is ever materialized.
+  * the online-softmax state of each KV head rides the loop's carry, and
+    each block computes every head's scores before any head's update, so
+    one head's matmuls never wait on another's softmax.
+  * on the TPU every row of a pool lies in memory as whole 128-lane rows,
+    and Mosaic's DMA moves whole rows: a copy takes a page's rows at that
+    padded width and the kernel reads their first D lanes, so any head
+    dim works (interpret mode copies the rows as they are).
+  * int8 pools bring (L, num_pages, KV, page, 1) f32 per-row scales,
+    copied beside their pages (one 128-lane row per scale on the TPU);
+    int8 rows are upcast to f32 and scaled by their rows' scales before
+    the matmuls, as the oracle dequantizes.
 
 Inference-only: no VJP (the jnp gather oracle in ``ref.py`` carries
 gradients where needed).
@@ -59,6 +61,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_BYTES = 512 * 1024     # VMEM one pool's block of pages takes
+_LANES = 128                 # a VMEM row holds a multiple of 128 lanes
 
 
 def grid_steps(num_blocks: int, pages_per_step: int) -> int:
@@ -72,68 +76,124 @@ def padded_blocks(num_blocks: int, pages_per_step: int) -> int:
     return grid_steps(num_blocks, pages_per_step) * max(1, pages_per_step)
 
 
+def block_pages(kv_heads: int, page: int, head_dim: int, dtype,
+                quantized: bool = False) -> int:
+    """Pages per loop iteration: as many as fit ``BLOCK_BYTES`` of VMEM
+    per pool, counting each page row at its padded lane width, and an
+    int8 row's f32 scale at the 128 lanes its copy takes."""
+    row = _padded(head_dim) * jnp.dtype(dtype).itemsize
+    if quantized:
+        row += _LANES * 4
+    return max(1, BLOCK_BYTES // (kv_heads * page * row))
+
+
+def block_trips(kv_len, block_tokens: int):
+    """Loop iterations for a slot: blocks of ``block_tokens`` that overlap
+    [0, kv_len) — ceil(kv_len / block_tokens), 0 for an empty slot.  The
+    kernel calls it on the SMEM scalar; tests call it on ints."""
+    return (kv_len + block_tokens - 1) // block_tokens
+
+
+def _padded(width: int) -> int:
+    """A row's width in TPU memory: whole 128-lane rows."""
+    return -(-width // _LANES) * _LANES
+
+
 def _kernel(kvlen_ref, tbl_ref, layer_ref, q_ref, *refs, scale: float,
-            page: int, num_steps: int, pages_per_step: int,
-            quantized: bool):
+            page: int, num_blocks: int, pages_per_step: int,
+            quantized: bool, copied: tuple):
     P = pages_per_step
-    k_refs = refs[:P]
-    v_refs = refs[P:2 * P]
-    if quantized:                        # int8 pages + per-row f32 scales
-        ks_refs = refs[2 * P:3 * P]
-        vs_refs = refs[3 * P:4 * P]
-        rest = refs[4 * P:]
-    else:
-        ks_refs = vs_refs = (None,) * P
-        rest = refs[2 * P:]
-    o_ref = rest[0]
-    m_scr, l_scr, acc_scr = rest[1:]
+    n_pools = 4 if quantized else 2      # k, v (+ their int8 row scales)
+    # buffers (2, P, KV, page, copied[i])
+    hbm, (o_ref, *bufs, sems) = refs[:n_pools], refs[n_pools:]
+    _, KV, G, D = q_ref.shape
+    T = P * page                         # tokens per block
     b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
     kv_len = kvlen_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32)                  # (G, D)
+    live_pages = block_trips(kv_len, page)
+    lay = layer_ref[0]
 
-    def _sweep(p, k_ref, v_ref, ks_ref, vs_ref):
-        # logical block j*P + p holds positions [bj*page, (bj+1)*page):
-        # live iff it overlaps [0, kv_len) — per-slot positions start at 0
-        bj = j * P + p
+    @pl.when(b == 0)
+    def _zero():
+        # rows past kv_len are masked, but a never-written buffer could
+        # hold NaN, and 0 * NaN would leak into the accumulator
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
 
-        @pl.when(bj * page < kv_len)
-        def _body():
-            k = k_ref[0, 0, 0].astype(jnp.float32)       # (page, D)
-            v = v_ref[0, 0, 0].astype(jnp.float32)
-            if quantized:                # dequantize in the f32 accumulator
-                k = k * ks_ref[0, 0, 0]                  # (page, 1) scales
-                v = v * vs_ref[0, 0, 0]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # (G, page)
-            tpos = bj * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(tpos < kv_len, s, NEG_INF)
-            m_prev = m_scr[...]
-            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            p_ = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[...] = l_scr[...] * corr + p_.sum(axis=1, keepdims=True)
-            acc_scr[...] = (acc_scr[...] * corr
-                            + jax.lax.dot_general(
-                                p_, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32))
-            m_scr[...] = m_new
+    def _copy(n, slot, fn):
+        """Apply ``fn`` (start or wait) to block n's live-page copies into
+        buffer ``slot``: one whole (KV, page, row) page per pool and page,
+        its rows at their ``copied`` width."""
+        def _page(p, carry):
+            phys = tbl_ref[b * num_blocks + n * P + p]
+            for i, (src, buf) in enumerate(zip(hbm, bufs)):
+                rows = src.at[lay, phys, :, :, pl.ds(0, copied[i])]
+                fn(pltpu.make_async_copy(rows, buf.at[slot, p],
+                                         sems.at[i, slot]))
+            return carry
 
-    for p in range(P):                   # unrolled page-list sweep
-        _sweep(p, k_refs[p], v_refs[p], ks_refs[p], vs_refs[p])
+        jax.lax.fori_loop(0, jnp.minimum(P, live_pages - n * P), _page, 0)
 
-    @pl.when(j == num_steps - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    def _rows(i, slot, h):
+        """Block rows (T, D) of kv head h from pool i (0 = k, 1 = v): the
+        first D lanes of each copied row; int8 rows are dequantized to f32
+        by their per-row scales."""
+        x = bufs[i][slot, :, h, :, pl.ds(0, D)]           # (P, page, D)
+        if quantized:
+            x = x.astype(jnp.float32) * bufs[2 + i][slot, :, h, :,
+                                                    pl.ds(0, 1)]
+        return x.reshape(T, D)
+
+    def _scores(h, slot, pos0):
+        """Masked scores (G, T) of kv head h's queries against the block's
+        rows at positions pos0.."""
+        q, k = q_ref[0, h], _rows(0, slot, h)             # (G, D), (T, D)
+        if k.dtype != q.dtype:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        return jnp.where(pos < kv_len, s, NEG_INF)
+
+    def _update(state, s, v):
+        """Online-softmax update of one kv head's (m, l, acc) with scores
+        s (G, T) over rows v (T, D)."""
+        m_prev, l_prev, acc = state
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p_ = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + p_.sum(axis=1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p_, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    def _block(n, state):
+        slot = n % 2
+
+        @pl.when(n + 1 < trips)
+        def _prefetch():
+            _copy(n + 1, 1 - slot, lambda c: c.start())
+
+        _copy(n, slot, lambda c: c.wait())
+        # every head's scores first, then every head's update: the
+        # heads' matmuls do not wait on each other's softmax
+        scores = [_scores(h, slot, n * T) for h in range(KV)]
+        return tuple(_update(state[h], scores[h], _rows(1, slot, h))
+                     for h in range(KV))
+
+    trips = block_trips(kv_len, T)
+
+    @pl.when(trips > 0)
+    def _first():
+        _copy(0, 0, lambda c: c.start())
+
+    init = (jnp.full((G, 1), NEG_INF, jnp.float32),
+            jnp.zeros((G, 1), jnp.float32), jnp.zeros((G, D), jnp.float32))
+    state = jax.lax.fori_loop(0, trips, _block, (init,) * KV)
+    for h, (_, l, acc) in enumerate(state):
+        # an empty slot (no trips) yields zeros
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention_fwd(q: jax.Array, k_pool: jax.Array,
@@ -142,20 +202,21 @@ def paged_decode_attention_fwd(q: jax.Array, k_pool: jax.Array,
                                layer: jax.Array | int = 0, *,
                                k_scale: jax.Array | None = None,
                                v_scale: jax.Array | None = None,
-                               pages_per_step: int = 1,
+                               pages_per_step: int | None = None,
                                interpret: bool = False) -> jax.Array:
     """q (B, 1, H, D); k_pool, v_pool (L, num_pages, KV, page, D) stacked
     head-major pools (a 4D (num_pages, KV, page, D) single-layer pool is
     promoted);
     block_table (B, max_blocks) int32 physical page ids (0 = reserved null
     page for unallocated blocks); kv_len (B,) int32 per-slot token counts
-    (positions >= kv_len[b] are masked); layer — which pool layer to
-    address (the layer-scan trip counter); k_scale, v_scale — optional
-    (L, num_pages, KV, page, 1) f32 per-row-per-head scales for int8 pools
-    (each page's scale rows ride the same scalar-prefetched address as the
-    page itself; int8 tiles are upcast and scaled inside the f32
-    online-softmax accumulator); pages_per_step — pages swept per grid
-    step (1 = the original one-page grid).  Returns (B, 1, H, D).
+    (positions >= kv_len[b] are masked; a slot at 0 yields zeros); layer —
+    which pool layer to address (the layer-scan trip counter); k_scale,
+    v_scale — optional (L, num_pages, KV, page, 1) f32 per-row-per-head
+    scales for int8 pools (int8 rows are upcast to f32 and scaled by
+    their rows' scales inside the kernel); pages_per_step —
+    pages copied per loop iteration (None = ``block_pages`` of the pool's
+    shape and dtype).
+    Returns (B, 1, H, D).
     """
     B, S, H, D = q.shape
     assert S == 1, "paged decode kernel is single-token"
@@ -164,59 +225,48 @@ def paged_decode_attention_fwd(q: jax.Array, k_pool: jax.Array,
         k_pool, v_pool = k_pool[None], v_pool[None]
         if quantized:
             k_scale, v_scale = k_scale[None], v_scale[None]
-    _, num_pages, KV, page, _ = k_pool.shape
+    _, _, KV, page, _ = k_pool.shape
     NB = block_table.shape[1]
-    P = max(1, pages_per_step)
-    steps = grid_steps(NB, P)
-    NBp = padded_blocks(NB, P)
+    P = pages_per_step or block_pages(KV, page, D, k_pool.dtype, quantized)
+    P = max(1, min(P, NB))               # a block never outgrows the table
     G = H // KV
     scale = 1.0 / math.sqrt(D)
 
     qg = q.reshape(B, KV, G, D)                  # kv-major head grouping
-    tbl = jnp.asarray(block_table, jnp.int32)
-    if NBp != NB:                                # pad with null-page entries
-        tbl = jnp.pad(tbl, ((0, 0), (0, NBp - NB)))
-    tbl = tbl.reshape(B * NBp)
+    tbl = jnp.asarray(block_table, jnp.int32).reshape(B * NB)
     kvl = jnp.asarray(kv_len, jnp.int32).reshape(B)
     lay = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def _page_map(p):
-        # the p-th page of grid step j: physical id tbl[b*NBp + j*P + p]
-        def index_map(b, h, j, kvl_ref, tbl_ref, lay_ref):
-            return (lay_ref[0], tbl_ref[b * NBp + j * P + p], h, 0, 0)
-        return index_map
-
-    page_spec = [pl.BlockSpec((1, 1, 1, page, D), _page_map(p))
-                 for p in range(P)]
-    # scale rows of the same physical page: (page, 1) per kv head
-    scale_spec = [pl.BlockSpec((1, 1, 1, page, 1), _page_map(p))
-                  for p in range(P)]
-    scale_ins = ([*scale_spec, *scale_spec] if quantized else [])
-    scale_args = (([k_scale] * P + [v_scale] * P) if quantized else [])
+    pools = [k_pool, v_pool] + ([k_scale, v_scale] if quantized else [])
+    # on the TPU a row lies in memory as whole 128-lane rows whatever its
+    # width, and Mosaic's DMA moves whole rows: each copy takes a page's
+    # rows at that padded width (the kernel reads their first D lanes, or
+    # an int8 scale's first); interpret mode copies the rows as they are
+    copied = tuple(x.shape[-1] if interpret else _padded(x.shape[-1])
+                   for x in pools)
     kernel = functools.partial(_kernel, scale=scale, page=page,
-                               num_steps=steps, pages_per_step=P,
-                               quantized=quantized)
+                               num_blocks=NB, pages_per_step=P,
+                               quantized=quantized, copied=copied)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, KV, steps),
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, j, *_: (b, h, 0, 0)),
-                *page_spec,                       # k pages 0..P-1
-                *page_spec,                       # v pages 0..P-1
-                *scale_ins,                       # k then v scales (int8)
+                pl.BlockSpec((1, KV, G, D), lambda b, *_: (b, 0, 0, 0)),
+                *[pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
             ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, j, *_: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, KV, G, D), lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),     # running row max
-                pltpu.VMEM((G, 1), jnp.float32),     # running row sum
-                pltpu.VMEM((G, D), jnp.float32),     # output accumulator
+                *[pltpu.VMEM((2, P, KV, page, w), x.dtype)
+                  for x, w in zip(pools, copied)],
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(kvl, tbl, lay, qg, *([k_pool] * P), *([v_pool] * P), *scale_args)
+    )(kvl, tbl, lay, qg, *pools)
     return out.reshape(B, 1, H, D)

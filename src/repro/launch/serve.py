@@ -109,10 +109,11 @@ def main() -> int:
                          "token per step (legacy prefill-by-decode, kept "
                          "for measured comparison)")
     ap.add_argument("--pages-per-step", type=int, default=1,
-                    help="paged decode kernel page-list blocking: pages "
-                         "swept per grid step (cuts grid steps by P for "
-                         "long slots; only meaningful with the pallas "
-                         "attention impl)")
+                    help="ragged paged-prefill kernel page-list "
+                         "blocking: pages swept per grid step (cuts grid "
+                         "steps by P for long slots; only meaningful with "
+                         "the pallas attention impl; the decode kernel "
+                         "derives its page block from the pool)")
     ap.add_argument("--kv-dtype", default="bf16", choices=("bf16", "int8"),
                     help="paged KV page pool storage: bf16 (model compute "
                          "dtype) or int8 (quantized pools + per-row f32 "

@@ -328,9 +328,10 @@ def attn_decode_paged(h, p, cfg: ArchConfig, rope, k_pool, v_pool, layer,
         if cfg.attention_impl == "pallas":
             from repro.kernels.decode_attention.ops import (
                 paged_decode_attention)
-            out = paged_decode_attention(q, k_pool, v_pool, table, kv_len,
-                                         layer,
-                                         pages_per_step=cfg.pages_per_step,
+            # an inactive slot's output is discarded: the kernel copies no
+            # page for it (kv_len 0 yields zeros)
+            out = paged_decode_attention(q, k_pool, v_pool, table,
+                                         jnp.where(active, kv_len, 0), layer,
                                          k_scale=k_scale, v_scale=v_scale)
         else:
             from repro.kernels.decode_attention.ref import (

@@ -199,7 +199,8 @@ COUNTERS = (
     "tokens_appended", "shared_tokens", "joins", "stalls", "dispatches",
     "spec_proposed", "spec_accepted", "spec_trunc_tokens",
     "draft_dispatches", "verify_dispatches", "table_upload_bytes",
-    "forced_upload_bytes", "prefill_upload_bytes", "upload_bytes")
+    "forced_upload_bytes", "prefill_upload_bytes", "upload_bytes",
+    "decode_pages_streamed", "decode_pages_table")
 
 # host spans on the JAX profiler's timeline; with no trace recording, a
 # span costs one native call and its attributes are never built
@@ -773,6 +774,12 @@ class PagedEngine:
         self.joins = 0
         self.stalls = 0
         self.dispatches = 0               # device calls of working ticks
+        # pages the decode kernel copies per layer, summed over the decode
+        # lane's active (step, slot) pairs: ceil(kv_len / page) each, and
+        # the whole table width each (their ratio: the share of the table
+        # the kernel still reads)
+        self.decode_pages_streamed = 0
+        self.decode_pages_table = 0
         self.util_trace: List[float] = []        # per-tick page utilization
         # --- tick-overhead accounting (the host side the roofline can't
         # see: BENCH_serve.json's tick_overhead section reads these) ------
@@ -900,6 +907,16 @@ class PagedEngine:
             text = lowered.compile().as_text()
             out[text.split(",", 1)[0].split()[1]] = text
         return out
+
+    def _count_decode_pages(self, steps, pgr, chunk: int) -> None:
+        """Decode step s of slot i attends over kv_len = (length after the
+        tick's prefill) + s + 1 tokens; count its pages."""
+        s = np.arange(chunk)[:, None]
+        kv_len = (self.kv.length + pgr)[None, :] + s + 1
+        act = s < steps[None, :]
+        pages = -(-kv_len // self.kv.page)
+        self.decode_pages_streamed += int(pages[act].sum())
+        self.decode_pages_table += int(act.sum()) * self.kv.max_blocks
 
     def counters(self) -> Dict[str, int]:
         """A snapshot of every cumulative counter, the engine's and its
@@ -1521,6 +1538,7 @@ class PagedEngine:
                     self.draft_dispatches += d_disp
                     self.verify_dispatches += v_disp
                 else:
+                    self._count_decode_pages(steps, pgr, chunk)
                     tick_upload += 2 * B * 4      # feed tokens + step grants
                     feed = jnp.asarray(self._feed)[:, None]
                     steps_dev = jnp.asarray(steps)

@@ -44,9 +44,13 @@ def test_chip_smoke_phases_at_reduced_width(smoke):
     report = smoke.check_kernels(cfg, slots=2, page=8, max_blocks=4,
                                  chunks=(8, 16))
     assert [name for name, _, _ in report] == [
-        "paged decode bf16 pages_per_step=1",
+        "paged decode bf16 pages_per_step=derived",
         "paged decode bf16 pages_per_step=4",
-        "paged decode int8 pages_per_step=1",
+        "paged decode int8 pages_per_step=derived",
         "paged decode int8 pages_per_step=4",
+        "paged decode bf16 qwen2-0.5b widths",
+        "paged decode int8 qwen2-0.5b widths",
+        "paged decode bf16 zamba2-7b widths",
+        "paged decode int8 zamba2-7b widths",
         "ragged prefill bf16 T=8", "ragged prefill bf16 T=16"]
     assert all(err <= smoke.KERNEL_TOL for _, err, _ in report)

@@ -185,27 +185,116 @@ def test_multipage_grid_arithmetic(NB, P, want):
     assert padded_blocks(NB, P) >= NB
 
 
-def test_multipage_kernel_runs_ceil_grid_steps(monkeypatch):
-    """The pages_per_step=4 kernel must RUN ceil(NB/4) grid steps per
-    (slot, kv-head) — asserted on the actual pallas grid, not just the
-    arithmetic helper."""
+def test_decode_kernel_runs_one_grid_step_per_slot(monkeypatch):
+    """The decode kernel's grid is (B,): one step per slot, whatever P.
+    Inside it each slot loops ``block_trips(kv_len, P * page)`` times —
+    the helper the kernel calls, spied on here with the block it gets
+    (and ``page`` for the live-page bound)."""
     import repro.kernels.decode_attention.paged as paged_mod
-    recorded = {}
-    orig = paged_mod.pltpu.PrefetchScalarGridSpec
+    recorded = {"tokens": set()}
+    orig_spec = paged_mod.pltpu.PrefetchScalarGridSpec
+    orig_trips = paged_mod.block_trips
 
-    def spy(*args, **kwargs):
-        recorded["grid"] = kwargs.get("grid", args[1] if len(args) > 1
-                                      else None)
-        return orig(*args, **kwargs)
+    def spec(*args, **kwargs):
+        recorded["grid"] = kwargs["grid"]
+        return orig_spec(*args, **kwargs)
 
-    monkeypatch.setattr(paged_mod.pltpu, "PrefetchScalarGridSpec", spy)
-    B, H, KV, D, page, NB, L = 2, 4, 2, 16, 8, 5, 1
-    q, kp, vp, tbl, lens, layer = _paged_case(0, B, H, KV, D, page, NB, L)
-    for pps, steps in ((4, 2), (2, 3), (1, 5)):
+    def trips(kv_len, block_tokens):
+        recorded["tokens"].add(block_tokens)
+        return orig_trips(kv_len, block_tokens)
+
+    monkeypatch.setattr(paged_mod.pltpu, "PrefetchScalarGridSpec", spec)
+    monkeypatch.setattr(paged_mod, "block_trips", trips)
+    B, H, KV, D, page, NB, L = 3, 4, 2, 16, 8, 5, 1
+    q, kp, vp, tbl, _, layer = _paged_case(0, B, H, KV, D, page, NB, L)
+    lens = jnp.asarray([0, 17, 40], jnp.int32)
+    for pps in (4, 2, 1):
+        recorded["tokens"].clear()
         paged_mod.paged_decode_attention_fwd(
             q, kp, vp, tbl, lens, layer, pages_per_step=pps, interpret=True)
-        assert recorded["grid"] == (B, KV, steps), \
-            f"pages_per_step={pps}: grid {recorded['grid']}"
+        assert recorded["grid"] == (B,), f"pages_per_step={pps}"
+        assert recorded["tokens"] == {page, pps * page}
+
+
+@pytest.mark.parametrize("kv_len,block,want", [
+    (0, 64, 0), (1, 64, 1), (64, 64, 1), (65, 64, 2), (40, 8, 5), (41, 8, 6),
+])
+def test_block_trips_counts_blocks_overlapping_kv_len(kv_len, block, want):
+    """A slot's loop covers [0, kv_len) in blocks: ceil(kv_len / block)
+    iterations, none for an empty slot or past the last live token."""
+    from repro.kernels.decode_attention.paged import block_trips
+    assert block_trips(kv_len, block) == want == -(-kv_len // block)
+
+
+@pytest.mark.parametrize("KV,page,D,dtype,quantized,want", [
+    (8, 16, 128, jnp.bfloat16, False, 16),   # phi4 / granite: 256 tokens
+    (8, 16, 128, jnp.int8, True, 6),         # int8 rows + 128-lane scales
+    (8, 16, 128, jnp.float32, False, 8),
+    (2, 8, 16, jnp.float32, False, 64),      # 16 lanes pad to 128
+])
+def test_block_pages_fits_the_vmem_block(KV, page, D, dtype, quantized, want):
+    """The page block comes from the pool's shape and dtype: the most
+    pages whose VMEM rows fit ``BLOCK_BYTES`` per pool."""
+    from repro.kernels.decode_attention.paged import BLOCK_BYTES, block_pages
+    got = block_pages(KV, page, D, dtype, quantized)
+    assert got == want
+    row = -(-D // 128) * 128 * jnp.dtype(dtype).itemsize + 512 * quantized
+    assert got * KV * page * row <= BLOCK_BYTES < (got + 1) * KV * page * row
+
+
+@pytest.mark.parametrize("B,H,KV,D,page,NB,P,lens,shared,int8", [
+    (4, 4, 2, 16, 8, 5, 2, (0, 1, 23, 40), False, False),  # empty, 1, full
+    (4, 4, 2, 16, 8, 4, 2, (8, 16, 32, 24), False, False),  # page/block edge
+    (2, 4, 2, 16, 8, 5, 3, (29, 13), False, False),  # P !| kv_len, P !| NB
+    (2, 4, 2, 16, 8, 5, 5, (40, 40), False, False),  # whole table, 1 block
+    (3, 4, 1, 16, 8, 4, 2, (30, 19, 0), True, False),  # shared prefix pages
+    (2, 4, 4, 16, 8, 4, None, (21, 32), False, False),  # group 1, derived P
+    (2, 6, 2, 32, 16, 3, None, (47, 5), False, False),  # group 3, derived P
+    (2, 8, 2, 16, 8, 4, 3, (31, 9), False, False),   # group 4
+    (4, 4, 2, 16, 1, 9, 4, (0, 1, 5, 9), False, False),  # page-1 rows
+    (4, 4, 2, 16, 8, 5, 2, (0, 1, 16, 33), False, True),  # int8 + scales
+])
+def test_paged_decode_reads_only_live_pages(B, H, KV, D, page, NB, P, lens,
+                                            shared, int8):
+    """Edge lengths against the gather oracle, with every page a slot's
+    kv_len does not reach (the null page included) filled with NaN: a
+    kernel that read one would return NaN.  An empty slot yields zeros.
+    ``shared``: slots 1.. reuse slot 0's first pages, as the prefix
+    cache maps them."""
+    from repro.kernels.decode_attention.ops import paged_decode_attention
+    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+    from repro.models.kv_quant import quantize_rows
+    q, kp, vp, tbl, _, layer = _paged_case(B * H + NB, B, H, KV, D, page,
+                                           NB, 2)
+    tbl = np.array(tbl)
+    if shared:
+        tbl[1:, :2] = tbl[0, :2]
+    live = np.zeros(kp.shape[1], bool)
+    for b, n in enumerate(lens):
+        live[tbl[b, :-(-n // page)]] = True
+    dead = jnp.asarray(~live)[None, :, None, None, None]
+    lens = jnp.asarray(lens, jnp.int32)
+    tbl = jnp.asarray(tbl, jnp.int32)
+    if int8:
+        (kp, ks), (vp, vs) = quantize_rows(kp), quantize_rows(vp)
+        clean = dict(k_scale=jnp.where(dead, 0.0, ks),
+                     v_scale=jnp.where(dead, 0.0, vs))
+        poisoned = dict(k_scale=jnp.where(dead, jnp.nan, ks),
+                        v_scale=jnp.where(dead, jnp.nan, vs))
+        pools = clean_pools = (kp, vp)
+    else:
+        clean, poisoned = {}, {}
+        pools = [jnp.where(dead, jnp.nan, x) for x in (kp, vp)]
+        clean_pools = [jnp.where(dead, 0.0, x) for x in (kp, vp)]
+    got = np.asarray(paged_decode_attention(
+        q, *pools, tbl, lens, layer, pages_per_step=P, interpret=True,
+        **poisoned))
+    want = np.asarray(paged_decode_attention_ref(q, *clean_pools, tbl, lens,
+                                                 layer, **clean))
+    empty = np.asarray(lens) == 0
+    assert (got[empty] == 0).all()
+    np.testing.assert_allclose(got[~empty], want[~empty],
+                               rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("pps", [1, 2, 4])
@@ -668,6 +757,30 @@ def test_steady_state_tick_uploads_zero_table_bytes(small_model):
     assert c1["dispatches"] - c0["dispatches"] == 1   # the fused decode cell
     assert c1["upload_bytes"] - c0["upload_bytes"] == \
         2 * pe.cfg.max_batch * 4                      # feed + grants
+
+
+def test_decode_page_counters_follow_live_lengths(small_model):
+    """``decode_pages_streamed`` adds ceil(kv_len / page) for every active
+    decode step of a dispatch (kv_len = the slot's length after the step's
+    append); ``decode_pages_table`` adds the table width for each."""
+    model, params = small_model
+    pe = PagedEngine(model, params,
+                     ServeConfig(max_batch=2, max_seq=64, max_new_tokens=12,
+                                 page_size=4, prefill_chunk=3))
+    pe.submit(np.arange(1, 8, dtype=np.int32))       # 7-token prompt
+    while any(s.active and s.forced for s in pe.slots) or pe.queue:
+        pe.step()                 # drain admission + prefill
+    i = next(j for j, s in enumerate(pe.slots) if s.active)
+    n0 = int(pe.kv.length[i])
+    c0 = pe.counters()
+    pe.step()                     # one decode dispatch of 3 steps, 1 slot
+    c1 = pe.counters()
+    steps = int(pe.kv.length[i]) - n0
+    assert steps == 3
+    assert c1["decode_pages_streamed"] - c0["decode_pages_streamed"] == \
+        sum(-(-(n0 + s + 1) // 4) for s in range(steps))
+    assert c1["decode_pages_table"] - c0["decode_pages_table"] == \
+        steps * pe.kv.max_blocks
 
 
 # ---------------------------------------------------------------------------

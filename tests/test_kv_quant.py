@@ -99,8 +99,8 @@ def _quantized_paged_case(seed, B, H, KV, D, page, NB, L, extra_pages=3):
 ])
 def test_quantized_paged_decode_matches_dequant_oracle(pps, B, H, KV, D,
                                                        page, NB, L):
-    """The decode sweep dequantizes P scattered pages per grid step through
-    the online softmax; with per-row scales threaded it must match the
+    """The decode kernel dequantizes P scattered pages per loop iteration
+    through the online softmax; with per-row scales threaded it must match the
     dequantizing jnp gather oracle."""
     from repro.kernels.decode_attention.ops import paged_decode_attention
     from repro.kernels.decode_attention.ref import paged_decode_attention_ref

@@ -1,10 +1,14 @@
 """Both paged Pallas kernels compile for a described TPU v5e at
 phi4-mini-3.8b's published attention widths (24 heads / 8 KV heads, head
-dim 128, 16-token pages), bf16 and int8 pools, one and four pages per grid
-step.  Nothing runs: the TPU compiler is asked for the executable, and the
-test asserts that it holds the Mosaic kernel (``tpu_custom_call``).  This
-is the check interpret mode cannot make — the compiler refuses block
-shapes that are not tiling-aligned, and interpret mode accepts them.
+dim 128, 16-token pages), bf16 and int8 pools: the ragged prefill kernel
+at one and four pages per grid step, the decode kernel at the page block
+it derives, also at granite-8b's widths (32 / 8 heads) and at head dims
+that are not a multiple of 128 lanes (qwen2-0.5b's 64, zamba2-7b's 112).
+Nothing runs:
+the TPU compiler is asked for the executable, and the test asserts that
+it holds the Mosaic kernel (``tpu_custom_call``).  This is the check
+interpret mode cannot make — the compiler refuses block shapes that are
+not tiling-aligned, and interpret mode accepts them.
 
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the worker running this
@@ -35,9 +39,9 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _pools(sharding, kv_dtype):
+def _pools(sharding, kv_dtype, cfg=CFG):
     """Stacked head-major K/V pools (+ int8 scale pools) as shapes."""
-    L, KV, hd = CFG.n_layers, CFG.n_kv_heads, CFG.head_dim
+    L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     dt = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
     pool = jax.ShapeDtypeStruct((L, NUM_PAGES, KV, PAGE, hd), dt,
                                 sharding=sharding)
@@ -56,23 +60,38 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("pps", [1, 4])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "granite-8b"])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_paged_decode_compiles_for_v5e(one_chip, kv_dtype, pps):
+def test_paged_decode_compiles_for_v5e(one_chip, kv_dtype, arch):
+    """The decode kernel at the page block it derives from the pool, at
+    phi4-mini's (24/8 heads) and granite-8b's (32/8) widths."""
+    _compile_decode(one_chip, kv_dtype, get(arch))
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "zamba2-7b"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_compiles_for_v5e_at_narrow_heads(one_chip, kv_dtype,
+                                                      arch):
+    """The decode kernel's page copies at head dims that leave part of a
+    128-lane row empty (qwen2-0.5b: 14/2 heads of 64, whose int8 scales
+    fill 32 lanes of a page; zamba2-7b: 32/32 heads of 112)."""
+    _compile_decode(one_chip, kv_dtype, get(arch))
+
+
+def _compile_decode(sharding, kv_dtype, cfg):
     from repro.kernels.decode_attention.paged import (
         paged_decode_attention_fwd)
-    pool, scale = _pools(one_chip, kv_dtype)
-    q = jax.ShapeDtypeStruct((SLOTS, 1, CFG.n_heads, CFG.head_dim),
-                             jnp.bfloat16, sharding=one_chip)
+    pool, scale = _pools(sharding, kv_dtype, cfg)
+    q = jax.ShapeDtypeStruct((SLOTS, 1, cfg.n_heads, cfg.head_dim),
+                             jnp.bfloat16, sharding=sharding)
 
     def step(q, k, v, tbl, lens, layer, ks, vs):
         return paged_decode_attention_fwd(q, k, v, tbl, lens, layer,
-                                          k_scale=ks, v_scale=vs,
-                                          pages_per_step=pps)
+                                          k_scale=ks, v_scale=vs)
 
     compiled = jax.jit(step).lower(
-        q, pool, pool, _ints((SLOTS, MAX_BLOCKS), one_chip),
-        _ints((SLOTS,), one_chip), _ints((), one_chip), scale,
+        q, pool, pool, _ints((SLOTS, MAX_BLOCKS), sharding),
+        _ints((SLOTS,), sharding), _ints((), sharding), scale,
         scale).compile()
     _assert_kernel(compiled)
 
