@@ -2,7 +2,8 @@
 the cell's own size: for each seed, one window of the cell's traffic
 through the served path (primed as a run is), then over the same seeded
 sample of requests that served tokens both the program's widest gap and
-the fp8 control's (the reference in fp8 put in the program's place).
+the fp8 control's (the configuration's reference in fp8 put in the
+program's place).
 Each goes through the check that decides a run's ``correct``
 (``run.check`` against bench/limits/<cell>.json, when the cell has one):
 the program has to come out correct, the control not.  The limit lies
@@ -26,9 +27,10 @@ sys.path[:0] = [str(Path(__file__).resolve().parents[1]),
 
 
 def readings(conf: dict, mix: dict, seed: int, seconds: float,
-             limits: dict, control: bool = True) -> dict:
+             limits: dict, reference, control: bool = True) -> dict:
     """One seed's window and the check of its program (and control)
-    gaps against ``limits``."""
+    gaps against ``limits``, both against ``reference``, the
+    configuration's module (``spec.load_reference``)."""
     from bench import correctness, generator, run, serve
     engine, weights, sz = serve.build(conf, seed)
     serve.warm(engine, sz.vocab)
@@ -40,13 +42,13 @@ def readings(conf: dict, mix: dict, seed: int, seconds: float,
     del engine
     gc.collect()
     items = [done[i] for i in correctness.sample(done, seed)]
-    ok, checks = run.check(weights, sz, done, seed, limits)
+    ok, checks = run.check(reference, weights, sz, done, seed, limits)
     out = {"seed": seed, "due": len(window.requests), "finished": finished,
            "served": len(done),
            "tokens": int(sum(len(s.tokens) for s in items)),
            "program": {"correct": ok, "checks": checks}}
     if control:
-        ok, checks = run.check(weights, sz, done, seed, limits,
+        ok, checks = run.check(reference, weights, sz, done, seed, limits,
                                gaps_of=correctness.control_gaps)
         out["control"] = {"correct": ok, "checks": checks}
     return out
@@ -65,6 +67,7 @@ def main() -> int:
     bench = spec.load_benchmark()
     cell = spec.find_cell(bench, args.workload)
     conf = spec.load_config(bench, cell)
+    reference = spec.load_reference(conf)
     mix = spec.load_mix(cell["traffic"])
     try:
         limits = run.load_limits(cell["name"])
@@ -73,7 +76,7 @@ def main() -> int:
     run.require_chip(cell["chips"])
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
-        r = readings(conf, mix, seed, args.seconds, limits,
+        r = readings(conf, mix, seed, args.seconds, limits, reference,
                      control=i < args.control_seeds)
         r["wall_s"] = time.perf_counter() - t
         print(json.dumps(r), flush=True)

@@ -2,8 +2,10 @@
 
 Once the window has closed, a sample drawn from the seed of the requests
 that served tokens in it, finished or still running at the close (always
-with the one that served the most tokens), is run through the float32
-reference over prompt + served tokens.
+with the one that served the most tokens), is run through the
+configuration's float32 reference (``spec.load_reference``: the module
+its file names, bench/reference.py by default) over prompt + served
+tokens.
 For every served token the gap is how far its reference logit lies below
 the reference's best logit at that position: 0 where the program picked
 the reference's argmax, small where rounding flipped a near-tie, large
@@ -21,7 +23,6 @@ from typing import List, Sequence
 
 import numpy as np
 
-from bench import reference
 from bench.spec import Sizes
 
 MIN_TOKENS = 384           # served tokens the sample holds at least
@@ -73,8 +74,10 @@ def _positions(s: Served):
     return seq, np.arange(P - 1, P - 1 + len(s.tokens))
 
 
-def served_gaps(weights, sz: Sizes, items: Sequence[Served]) -> np.ndarray:
-    """Per served token: reference best logit minus the served token's."""
+def served_gaps(reference, weights, sz: Sizes,
+                items: Sequence[Served]) -> np.ndarray:
+    """Per served token: reference best logit minus the served token's.
+    ``reference`` is the configuration's module (``spec.load_reference``)."""
     out = []
     for s in items:
         seq, rows = _positions(s)
@@ -83,7 +86,8 @@ def served_gaps(weights, sz: Sizes, items: Sequence[Served]) -> np.ndarray:
     return np.concatenate(out) if out else np.zeros((0,))
 
 
-def control_gaps(weights, sz: Sizes, items: Sequence[Served]) -> np.ndarray:
+def control_gaps(reference, weights, sz: Sizes,
+                 items: Sequence[Served]) -> np.ndarray:
     """Per position of the same sequences: reference best logit minus the
     reference logit of the token the fp8 reference puts first."""
     out = []

@@ -2,6 +2,27 @@
 describe, in straightforward ``jax.numpy``: no kernels, no cache, no
 batching, ``jax.default_matmul_precision("highest")``.
 
+It is the reference of every config file that names none.  A config of
+another architecture names its own module under ``"reference"`` (a path
+from the checkout root, e.g. ``"bench/<module>.py"``), found by
+``spec.load_reference``, which implements the same interface:
+
+    logits(weights, sizes, tokens, rows, fp8=False) -> np.ndarray
+
+  weights  the benchmark's weights (bench/weights.py): the tree of the
+           program's ``abstract_params()``, made from the run's seed;
+  sizes    ``spec.Sizes`` of the config file: the dense fields, and any
+           other published key through ``sizes.get(key)``;
+  tokens   int32 (S,), one sequence: prompt + served tokens;
+  rows     int32 positions into it; the logits at row p predict token
+           p + 1;
+  fp8      the lower-precision control: every matmul operand rounded to
+           float8 e4m3 with absmax scales (below);
+
+and returns float32 logits (len(rows), vocab).  It imports nothing of the
+program, and is whole in itself: it copies what it needs of this module
+rather than call its private helpers.
+
 Per layer: RMSNorm -> q, k, v projections -> rotary embedding (rotate-half,
 inverse frequencies theta^(-i / (hd/2)), over the whole head dim) ->
 causal softmax attention, query head h reading key/value head

@@ -8,12 +8,12 @@ warms up every program the window runs and drives the cell's traffic
 (bench/traffic/<mix>.json) through ``submit``/``step`` for the mix's
 ``prime_s`` (set-up, so the window opens on an engine in steady state),
 then for ``--seconds`` (the window), then checks the served tokens
-against the plain float32
-reference (bench/correctness.py).  With ``--trace 0`` the result holds the
-cell's end-to-end metrics; with ``--trace 1`` a sub-window is traced with
-the JAX profiler and the result holds the per-layer metrics
-(bench/metrics/<name>.py), the device's busy and window seconds and a
-breakdown.
+against the configuration's plain float32 reference (bench/correctness.py;
+the module its file names, bench/reference.py by default).  With
+``--trace 0`` the result holds the cell's end-to-end metrics; with
+``--trace 1`` a sub-window is traced with the JAX profiler and the result
+holds the per-layer metrics (bench/metrics/<name>.py), the device's busy
+and window seconds and a breakdown.
 
 The last line of standard output is one JSON object.  Without a TPU, with
 fewer chips than the cell asks for, or on a device kind missing from
@@ -126,17 +126,18 @@ def load_limits(cell: str, root: Path = ROOT) -> dict:
                       .read_text())
 
 
-def check(weights, sz, done, seed: int, limits: dict,
+def check(reference, weights, sz, done, seed: int, limits: dict,
           gaps_of=None):
     """(correct, checks): the served tokens of a seeded sample of the
     requests that served tokens in the window (``correctness.at_close``)
-    against the plain reference.  ``gaps_of`` puts another source of
-    gaps in the program's place (bench/control.py: the fp8 control)."""
+    against the configuration's plain reference (``spec.load_reference``).
+    ``gaps_of`` puts another source of gaps in the program's place
+    (bench/control.py: the fp8 control)."""
     import numpy as np
     from bench import correctness
     gaps_of = gaps_of or correctness.served_gaps
     items = [done[i] for i in correctness.sample(done, seed)]
-    gaps = gaps_of(weights, sz, items)
+    gaps = gaps_of(reference, weights, sz, items)
     worst = float(gaps.max()) if gaps.size else float("nan")
     lim = limits["logit_gap_max"]["limit"]
     print(f"served tokens checked: {gaps.size} in {len(items)} of "
@@ -150,6 +151,7 @@ def run(args, root: Path = ROOT, chip=require_chip) -> dict:
     bench = spec.load_benchmark(root)
     cell = spec.find_cell(bench, args.workload)
     conf = spec.load_config(bench, cell, root)
+    reference = spec.load_reference(conf, root)
     mix = spec.load_mix(cell["traffic"], root)
     limits = load_limits(cell["name"], root)
     devices, peaks = chip(cell["chips"])
@@ -190,7 +192,8 @@ def run(args, root: Path = ROOT, chip=require_chip) -> dict:
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
 
-    correct, checks = check(weights, sz, done, args.seed, limits)
+    correct, checks = check(reference, weights, sz, done, args.seed,
+                            limits)
     admitted = sum(r.admit_t is not None for r in window.requests)
     print(f"requests due {len(window.requests)}, admitted {admitted}, "
           f"finished {finished}, failed {failed}, ticks "

@@ -74,21 +74,27 @@ class Window:
         return self.t1 - self.t0
 
 
+def arch_config(conf: dict):
+    """The program's ``ArchConfig`` for a config file: its registry entry,
+    then the file's published sizes, then its ``program_settings`` last,
+    so that the program's own name for a width wins (``d_ff`` is a routed
+    expert's width, onto which the published ``intermediate_size`` maps)."""
+    from repro import configs
+    fields = {**Sizes.from_config(conf).arch_overrides(),
+              **conf["program_settings"]}
+    return dataclasses.replace(configs.get(conf["registry_id"]), **fields)
+
+
 def build(conf: dict, seed: int):
     """(engine, weights, sizes) for a config file, weights from ``seed``."""
-    from repro import configs
     from repro.models import get_model
     from repro.serve.engine import PagedEngine, ServeConfig
 
-    sz = Sizes.from_config(conf)
-    cfg = dataclasses.replace(configs.get(conf["registry_id"]),
-                              **conf["program_settings"],
-                              **sz.arch_overrides())
-    model = get_model(cfg)
+    model = get_model(arch_config(conf))
     params = weights.make(model.abstract_params(), seed)
     scfg = ServeConfig(max_seq=conf["max_context"], temperature=0.0,
                        eos_id=-1, **conf["serving"])
-    return PagedEngine(model, params, scfg), params, sz
+    return PagedEngine(model, params, scfg), params, Sizes.from_config(conf)
 
 
 def warm(engine, vocab: int) -> None:

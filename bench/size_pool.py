@@ -13,7 +13,6 @@ within ``--usable`` bytes.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
@@ -38,18 +37,14 @@ def main() -> int:
     from jax.sharding import SingleDeviceSharding
 
     jax.config.update("jax_enable_compilation_cache", False)
-    from repro import configs
     from repro.kernels.decode_attention import ops
     from repro.models import get_model
-    from bench.spec import Sizes
+    from bench.serve import arch_config
 
     ops.auto_interpret = lambda interpret=None: False   # lower for the chip
     conf = json.loads(Path(args.config).read_text())
     srv = conf["serving"]
-    sz = Sizes.from_config(conf)
-    cfg = dataclasses.replace(configs.get(conf["registry_id"]),
-                              **conf["program_settings"],
-                              **sz.arch_overrides())
+    cfg = arch_config(conf)
     model = get_model(cfg)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -83,7 +78,8 @@ def main() -> int:
 
     w_bytes = sum(x.size * x.dtype.itemsize
                   for x in jax.tree.leaves(model.abstract_params()))
-    page_bytes = 2 * sz.layers * sz.kv_heads * page * sz.head_dim * 2
+    page_bytes = (2 * cfg.n_layers * cfg.n_kv_heads * page * cfg.head_dim
+                  * 2)
     out = {"weights_bytes": w_bytes, "page_bytes": page_bytes,
            "num_pages_compiled": pages}
     temps = []

@@ -8,7 +8,7 @@ import json
 
 import pytest
 
-from bench import control
+from bench import control, spec
 from bench.tests import tiny
 
 LIMIT = tiny.LIMIT
@@ -17,9 +17,11 @@ LIMIT = tiny.LIMIT
 @pytest.mark.parametrize("seed", [3, 2**31 + 5, 10**9 + 7])
 def test_control_fails_where_program_passes(seed, tmp_path):
     limits = {"logit_gap_max": {"limit": LIMIT}}
-    r = control.readings(dict(tiny.CONFIG, name="tiny"),
+    conf = dict(tiny.CONFIG, name="tiny")
+    r = control.readings(conf,
                          dict(tiny.MIX, prime_s=0.0), seed,
-                         seconds=2.0, limits=limits)
+                         seconds=2.0, limits=limits,
+                         reference=spec.load_reference(conf))
     print(json.dumps(r))
     assert r["tokens"] > 0
     prog, ctrl = r["program"], r["control"]

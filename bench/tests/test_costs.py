@@ -1,9 +1,12 @@
 """bench/costs.py against counts made by hand at a tiny shape: 2 layers,
 d_model 8, 2 heads over 1 KV head of width 4, d_ff 16, vocab 10."""
+import dataclasses
+import json
+
 import pytest
 
 from bench import costs
-from bench.spec import Sizes
+from bench.spec import Sizes, SpecError
 
 SZ = Sizes(layers=2, d_model=8, heads=2, kv_heads=1, head_dim=4, d_ff=16,
            vocab=10, tied=True, rope_theta=1e4, norm_eps=1e-5)
@@ -35,3 +38,28 @@ def test_model_flops():
                                 costs.decode_attention])
 def test_idle_slots_cost_nothing(fn):
     assert fn(SZ, [7, 9], [0, 0]) == (0.0, 0.0)
+
+
+def _published(**keys):
+    return dataclasses.replace(SZ, published=json.dumps(keys))
+
+
+@pytest.mark.parametrize("fn", [costs.linear_flops_per_token,
+                                lambda sz: costs.prefill_model_flops(
+                                    sz, [3, 0], [2, 0]),
+                                lambda sz: costs.decode_model_flops(
+                                    sz, [3, 5], [2, 0])])
+def test_routed_experts_are_not_counted_as_a_dense_ffn(fn):
+    with pytest.raises(SpecError, match="routed-expert"):
+        fn(_published(num_experts=4, num_experts_per_tok=2))
+
+
+@pytest.mark.parametrize("fn", [costs.prefill_attention,
+                                costs.decode_attention])
+def test_attention_past_the_window_is_not_counted_as_full(fn):
+    # slot 0 sees 3 + 2 = 5 keys at most: a window of 5 is full causal,
+    # a window of 4 cuts its last query
+    assert fn(_published(sliding_window=5), [3, 0], [2, 0]) == \
+        fn(SZ, [3, 0], [2, 0])
+    with pytest.raises(SpecError, match="sliding_window 4"):
+        fn(_published(sliding_window=4), [3, 0], [2, 0])

@@ -38,7 +38,7 @@ MIX = {
 }
 
 BATCH = {
-    "loop": "closed", "clients": 6, "requests_per_client": 3,
+    "loop": "closed", "clients": 6, "requests_per_client": 30,
     "prime_s": 1.0,
     "user": {"dist": "lognormal", "median": 24, "sigma": 0.6, "lo": 8,
              "hi": 64},
