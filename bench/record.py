@@ -2,6 +2,34 @@
 
 A reader takes a ``Record`` and returns a number, or None when the run
 holds nothing for it to read (the harness then leaves the metric out).
+A reader never returns 0 for a share of a roofline or of a peak that it
+could not read.  What a ``Record`` offers, and when each part is empty:
+
+  * ``window`` (bench/serve.py ``Window``), in every run: the requests due
+    in the window with their host-clock stamps, each tick's host interval
+    and plan grants (``lengths``, ``prefill``, ``steps``), the tokens
+    emitted, and ``traced``, the (first, last + 1) ticks of the traced
+    part of a ``--trace 1`` run (None in a ``--trace 0`` run);
+  * ``counters``, in every run: the deltas of every key of
+    ``PagedEngine.counters()`` (``tokens_out``, ``recompute_tokens``,
+    ``decode_pages_streamed``, ...) from the window's first tick boundary
+    to its close, keyed by the engine's own names, so a counter the
+    program adds reaches a reader with no edit here;
+  * ``counters_traced``: the same deltas over the traced ticks; empty in
+    a ``--trace 0`` run;
+  * ``trace``, with ``--trace 1`` only (else None): ``trace.reduce`` of
+    the traced part, per lane (``lane(name)``) its module runs and
+    seconds and its attention kernel's seconds, and the device's busy
+    and window seconds; a Mosaic call named under the config's
+    ``trace_kernels`` is left out of a lane's kernel time;
+  * ``spans``, with ``--trace 1`` only (else None): ``spans.reduce`` of
+    the same trace (``span_lane(name)``), per lane its device seconds in
+    each named scope and kernel, the config's ``trace_scopes`` and
+    ``trace_kernels`` among them, and the idle time by engine span.
+
+A trace with no TPU plane (the CPU) holds no lane in ``trace`` or
+``spans``: ``lane`` and ``span_lane`` give None there, and so do the
+readers that need them.
 """
 from __future__ import annotations
 
@@ -28,6 +56,17 @@ class Record:
     window: Window
     setup_s: float
     trace: Optional[Dict[str, object]] = None   # trace.reduce() of a run
+    spans: Optional[Dict[str, object]] = None   # spans.reduce() of a run
+
+    @property
+    def counters(self) -> Dict[str, int]:
+        """The engine's counters over the window (``Window.counters``)."""
+        return self.window.counters
+
+    @property
+    def counters_traced(self) -> Dict[str, int]:
+        """The engine's counters over the traced ticks; {} untraced."""
+        return self.window.counters_traced
 
     @property
     def requests(self) -> List[Req]:
@@ -55,6 +94,14 @@ class Record:
         if self.trace is None:
             return None
         lane = self.trace["lanes"].get(name)
+        return lane if lane and lane["runs"] else None
+
+    def span_lane(self, name: str) -> Optional[Dict[str, object]]:
+        """A lane of ``spans``: runs, module seconds, and device seconds
+        by scope and by kernel; None where the run holds none."""
+        if self.spans is None:
+            return None
+        lane = self.spans["lanes"].get(name)
         return lane if lane and lane["runs"] else None
 
     def prefill_work(self, fn) -> float:

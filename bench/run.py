@@ -13,7 +13,11 @@ the module its file names, bench/reference.py by default).  With
 ``--trace 0`` the result holds the cell's end-to-end metrics; with
 ``--trace 1`` a sub-window is traced with the JAX profiler and the result
 holds the per-layer metrics (bench/metrics/<name>.py), the device's busy
-and window seconds and a breakdown.
+and window seconds and a breakdown.  The readers get a ``bench/record.py``
+``Record``: the window, the engine's counters over it and over its traced
+part, and, after the window has closed, the trace reduced per lane
+(bench/trace.py) and per named scope and kernel (bench/spans.py, through
+the lanes' compiled HLO, ``PagedEngine.cell_hlo()``).
 
 The last line of standard output is one JSON object.  Without a TPU, with
 fewer chips than the cell asks for, or on a device kind missing from
@@ -156,7 +160,7 @@ def run(args, root: Path = ROOT, chip=require_chip) -> dict:
     limits = load_limits(cell["name"], root)
     devices, peaks = chip(cell["chips"])
 
-    from bench import correctness, generator, record, serve, trace
+    from bench import correctness, generator, record, serve, spans, trace
     engine, weights, sz = serve.build(conf, args.seed)
     serve.warm(engine, sz.vocab)
     sessions = generator.schedule(mix, args.seed, args.seconds, sz.vocab)
@@ -177,14 +181,19 @@ def run(args, root: Path = ROOT, chip=require_chip) -> dict:
     finished = sum(1 for r in window.requests if r.ok)
     done = correctness.at_close(engine, window.primed + window.requests)
     failed = sum(1 for r in window.requests if r.ok is False)
+    hlo = engine.cell_hlo() if args.trace else None
     del engine, loop
     gc.collect()
 
-    red = None
+    red = sp = None
     if args.trace:
-        red = trace.reduce(trace.load(log_dir))
+        red = trace.reduce(trace.load(log_dir),
+                           other_kernels=conf.get("trace_kernels", ()))
+        sp = spans.reduce(spans.load(log_dir, hlo,
+                                     conf.get("trace_scopes", ())),
+                          conf.get("trace_kernels", ()))
         shutil.rmtree(log_dir, ignore_errors=True)
-    rec = record.Record(sz, peaks, window, setup_s, red)
+    rec = record.Record(sz, peaks, window, setup_s, red, sp)
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = {}
     for m in spec.cell_metrics(bench, cell["name"], kind):

@@ -12,7 +12,9 @@ retained prefixes in the state the traffic keeps them in; the requests
 due then are set-up and not measured.  Everything the metrics read is
 recorded from outside the engine: per request its due, admission, first and last
 token times on the host clock; per tick its host interval and the plan's
-grants; and the engine's own counters.
+grants; and the engine's own counters (``PagedEngine.counters()``), taken
+between ticks where the window and its traced part open and where the
+window closes.
 """
 from __future__ import annotations
 
@@ -68,6 +70,11 @@ class Window:
     tokens: int                     # tokens emitted in ticks after opened
     traced: Optional[tuple] = None  # (first, last + 1) tick index traced
     primed: List[Req] = dataclasses.field(default_factory=list)  # set-up
+    # engine.counters() deltas, every key the engine gives: from ``opened``
+    # to the close, and from the traced part's opening to the close ({}
+    # where nothing was traced)
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
+    counters_traced: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def seconds(self) -> float:
@@ -157,7 +164,9 @@ class Driver:
         tick boundary after the window opens.  With ``trace``, the
         context it returns is opened at the first tick boundary
         ``trace_at`` seconds into the window and closed when the window
-        ends, so no trace is written while the window runs."""
+        ends, so no trace is written while the window runs.  The
+        engine's counters are snapshot at those two boundaries and at
+        the close."""
         from repro.serve.engine import RequestStatus, TERMINAL_STATUSES
         eng = self.engine
         done_ok = (RequestStatus.FINISHED, RequestStatus.PREEMPTED_RESUMED)
@@ -171,6 +180,7 @@ class Driver:
         ticks: List[Tick] = []
         shared0 = None
         traced = tracing = None
+        at = {}                             # engine.counters() snapshots
 
         def submit(due, si, ti):
             s = sessions[si]
@@ -234,6 +244,7 @@ class Driver:
             if shared0 is None and now >= t0:
                 opened = now
                 shared0 = eng.shared_tokens
+                at["open"] = eng.counters()
                 if at_open is not None:
                     at_open()
             if trace is not None and traced is None \
@@ -241,6 +252,7 @@ class Driver:
                 tracing = trace(WINDOW)
                 tracing.__enter__()
                 traced = len(ticks)
+                at["traced"] = eng.counters()
             if now >= t_end:
                 break
             while heap and heap[0][0] <= now:
@@ -267,14 +279,20 @@ class Driver:
             ticks.append(Tick(ts, te, host_ms, lengths, pre, steps,
                               len(queued)))
         t1 = clock()
+        close = eng.counters()
         if tracing is not None:
             tracing.__exit__(None, None, None)
+
+        def since(snap):
+            return {k: v - snap[k] for k, v in close.items()} if snap else {}
+
         return Window(t0, t1, [r for r in reqs.values() if r.due >= t0],
                       ticks, eng.shared_tokens - shared0,
                       eng.cfg.prefill_chunk, eng.cfg.max_batch, opened,
                       tokens,
                       None if traced is None else (traced, len(ticks)),
-                      [r for r in reqs.values() if r.due < t0])
+                      [r for r in reqs.values() if r.due < t0],
+                      since(at.get("open")), since(at.get("traced")))
 
 
 WINDOW = "traced_window"

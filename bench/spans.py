@@ -23,6 +23,15 @@ stats are the device offset and duration alone (checked on a v5e trace).
 So an op's scope comes from the compiled HLO text of its module, which
 ``PagedEngine.cell_hlo()`` gives for both lanes (``hlo``: module name ->
 text) and which maps each instruction's name to its ``op_name``.
+
+A configuration may add names of its own: its file's ``trace_scopes`` are
+read as scopes beside ``SCOPES`` (``load(..., scopes=)``), and its
+``trace_kernels`` as kernels beside ``KERNELS`` (``reduce(...,
+kernels=)``), for that configuration's cells only.  An op takes a scope
+only where exactly one known name is in its ``op_name`` path, so a
+configuration's scope must not nest inside one of ``SCOPES`` (a
+``moe_experts`` inside ``ffn`` would leave its ops in no scope): it takes
+the place of the scope it would nest in.
 """
 from __future__ import annotations
 
@@ -112,10 +121,10 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _TARGET = re.compile(r'custom_call_target="[^"]*"')
 
 
-def scope_of(op_name: str) -> str:
+def scope_of(op_name: str, names: Sequence[str] = SCOPES) -> str:
     """The one scope among an ``op_name``'s path components ("" where
     there is none, or more than one)."""
-    found = [c for c in op_name.split("/") if c in SCOPES]
+    found = [c for c in op_name.split("/") if c in names]
     return found[0] if len(found) == 1 else ""
 
 
@@ -135,7 +144,7 @@ def cut(text: str) -> str:
     return f"{head} ... {target.group(0)}" if target else head
 
 
-def hlo_scopes(text: str) -> Dict[str, str]:
+def hlo_scopes(text: str, names: Sequence[str] = SCOPES) -> Dict[str, str]:
     """Instruction name -> scope, from a compiled module's HLO text.  A
     fusion with no ``op_name`` of its own takes its root instruction's
     scope, else the one scope its fused instructions share.  A scatter
@@ -157,7 +166,7 @@ def hlo_scopes(text: str) -> Dict[str, str]:
         if not m:
             continue
         op = _OP_NAME.search(line)
-        sc = scope_of(op.group(1)) if op else ""
+        sc = scope_of(op.group(1), names) if op else ""
         if not op and m.group(2) == "scatter":
             sc = "kv_write"
         own[m.group(1)] = (m.group(2), sc)
@@ -183,12 +192,13 @@ def _jit_name(module: str) -> str:
     return module.split("(")[0]
 
 
-def load(log_dir: str, hlo: Dict[str, str]) -> Spans:
+def load(log_dir: str, hlo: Dict[str, str],
+         scopes: Sequence[str] = ()) -> Spans:
     """Read the newest ``.xplane.pb`` under ``log_dir``: its device ops and
     module runs as ``trace.load`` reads them, and the host plane's spans
     with their attributes.  ``hlo`` maps a module's jit name to its
     compiled HLO text (``PagedEngine.cell_hlo``), which names each op's
-    scope."""
+    scope among ``SCOPES`` and a configuration's own ``scopes``."""
     from jax.profiler import ProfileData
     tr = trace.load(log_dir)
     files = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
@@ -202,15 +212,17 @@ def load(log_dir: str, hlo: Dict[str, str]) -> Spans:
                          if e.name.startswith(ENGINE)
                          or e.name in trace.HOST_SPANS]
     modules = [HostSpan(m.name, m.start, m.end) for m in tr.modules]
-    return Spans(build_ops(tr.ops, modules, hlo), modules, host, tr.chips)
+    return Spans(build_ops(tr.ops, modules, hlo, scopes), modules, host,
+                 tr.chips)
 
 
 def build_ops(ops: Sequence[trace.Span], modules: Sequence[HostSpan],
-              hlo: Dict[str, str]) -> List[Op]:
+              hlo: Dict[str, str], scopes: Sequence[str] = ()) -> List[Op]:
     """``Op``s from ``trace.load``'s device ops: each op's module is the
     one ``trace.attribute`` gives it, and its scope is its instruction's
-    in that module's HLO text."""
-    maps = {k: hlo_scopes(v) for k, v in hlo.items()}
+    in that module's HLO text, among ``SCOPES`` and ``scopes``."""
+    known = SCOPES + tuple(scopes)
+    maps = {k: hlo_scopes(v, known) for k, v in hlo.items()}
     return [Op(cut(o.name), o.start, o.end, o.module,
                maps.get(_jit_name(o.module), {}).get(instruction(o.name)[0],
                                                     ""))
@@ -227,10 +239,10 @@ def _lane(module: str) -> Optional[str]:
     return None
 
 
-def kernel_of(op: Op) -> str:
+def kernel_of(op: Op, kernels: Sequence[str] = KERNELS) -> str:
     """The Pallas kernel an op is ("" for any other op)."""
     base = op.name.split(".")[0]
-    return base if base in KERNELS else ""
+    return base if base in kernels else ""
 
 
 def innermost(host: Sequence[HostSpan], t: float) -> str:
@@ -247,11 +259,13 @@ def window(sp: Spans) -> Tuple[float, float]:
     return (min(o.start for o in sp.ops), max(o.end for o in sp.ops))
 
 
-def reduce(sp: Spans) -> Dict[str, object]:
+def reduce(sp: Spans, kernels: Sequence[str] = ()) -> Dict[str, object]:
     """Per lane: module runs and seconds, device seconds per scope, per
-    kernel and without a scope, and the scoped share of module time; the
-    window's idle seconds by the innermost engine span open across each
-    gap; and each engine span's count and host seconds."""
+    kernel (``KERNELS`` and a configuration's own ``kernels``) and
+    without a scope, and the scoped share of module time; the window's
+    idle seconds by the innermost engine span open across each gap; and
+    each engine span's count and host seconds."""
+    known = KERNELS + tuple(kernels)
     w0, w1 = window(sp)
     lanes: Dict[str, Dict[str, object]] = {}
     for m in sp.modules:
@@ -274,7 +288,7 @@ def reduce(sp: Spans) -> Dict[str, object]:
             lane["unscoped_s"] += s
             key = o.name.rstrip("0123456789").rstrip(".")
             lane["unscoped_ops"][key] = lane["unscoped_ops"].get(key, 0.0) + s
-        k = kernel_of(o)
+        k = kernel_of(o, known)
         if k:
             lane["kernels"][k] = lane["kernels"].get(k, 0.0) + s
     for d in lanes.values():

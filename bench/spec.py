@@ -8,6 +8,12 @@ by adding files and entries, never by editing one:
   * ``BENCHMARK.json`` ``configs[].file``      — model sizes + serving settings
   * the module a config file names under ``reference`` (default
     ``bench/reference.py``)                     — its plain reference
+  * a config file's ``trace_scopes`` and ``trace_kernels`` (lists of
+    names, default none)                        — the named scopes and
+    Pallas kernels its program adds, read from a ``--trace 1`` run beside
+    the dense decoder's (bench/spans.py); a kernel named here is no
+    lane's attention kernel (bench/trace.py), so it brings a roofline
+    reader of its own
   * ``bench/traffic/<traffic>.json``           — a traffic mix (parameters)
   * ``bench/metrics/<metric>.py``              — one per-layer metric reader
 
@@ -115,7 +121,7 @@ def load_reference(conf: dict, root: Path = ROOT):
 HARNESS_KEYS = frozenset({
     "name", "source", "paper", "registry_id", "program_settings",
     "reference", "reduced", "reduced_from", "deployment", "departures",
-    "assumed", "max_context", "serving"})
+    "assumed", "max_context", "serving", "trace_scopes", "trace_kernels"})
 
 # Sizes' dense fields and the published (Hugging Face) key each is read from.
 DENSE_KEYS = {

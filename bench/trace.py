@@ -23,7 +23,15 @@ in the op's text) inside that lane's modules: the prefill lane holds only
 the ragged prefill kernel and the decode cell only the paged decode
 kernel (checked by hand on a v5e trace of phi4-mini: 32 Mosaic calls per
 prefill run and 32 x 4 per decode run; the lanes' other custom calls are
-XLA's own, ConcatBitcast and AllocateBuffer, of about no time).
+XLA's own, ConcatBitcast and AllocateBuffer, of about no time).  A lane's
+kernel time is its attention kernel's: a configuration that puts another
+Pallas kernel in a lane (a grouped matmul) names it under ``trace_kernels``
+in its file, and ``reduce(..., other_kernels=)`` leaves the Mosaic calls
+named after it out of the lane's kernel time and count.  They stay in the
+lane's module time, in busy time and in the breakdown.  The attention
+kernels are found by that exclusion and not by their names, since a
+trace from before the kernels were named (data/trace_v5e.json) calls
+them ``%closed_call.N``.
 """
 from __future__ import annotations
 
@@ -173,8 +181,11 @@ def _host_label(host: Sequence[Span], t: float) -> str:
     return "/".join(s.name for s in sorted(inner, key=lambda s: -s.dur))
 
 
-def reduce(tr: Trace, top: int = 10) -> Dict[str, object]:
-    """The per-layer numbers of one traced window (times in seconds)."""
+def reduce(tr: Trace, top: int = 10,
+           other_kernels: Sequence[str] = ()) -> Dict[str, object]:
+    """The per-layer numbers of one traced window (times in seconds).
+    ``other_kernels``: Pallas kernels, by name, whose Mosaic calls are no
+    lane's attention kernel."""
     win = [s for s in tr.host if s.name == WINDOW_SPAN]
     if win:
         w0, w1 = win[0].start, win[0].end
@@ -188,6 +199,7 @@ def reduce(tr: Trace, top: int = 10) -> Dict[str, object]:
     busy = _union((o.start, o.end) for o in ops)
     chips = max(1, tr.chips)
     busy_s = sum(e - s for s, e in busy) / chips / 1e9
+    other = tuple(other_kernels)
     lanes: Dict[str, Dict[str, float]] = {}
     for m in tr.modules:
         lane = _lane(m.name, kernel_modules)
@@ -200,7 +212,8 @@ def reduce(tr: Trace, top: int = 10) -> Dict[str, object]:
     for o in ops:
         code = opcode(o.name)
         lane = _lane(o.module, kernel_modules)
-        if lane in lanes and KERNEL_TARGET in o.name:
+        if lane in lanes and KERNEL_TARGET in o.name \
+                and not o.name.lstrip("%").startswith(other):
             lanes[lane]["kernel_s"] += o.dur / 1e9
             lanes[lane]["kernel_ops"] += 1
         if code not in PARENT_OPCODES:
